@@ -18,7 +18,8 @@ sequence's logical block ``i`` is found through a scalar-prefetched block
 table — the BlockSpec index map reads ``table[b, i]`` to aim the next DMA,
 so the gather never materializes.  Same online-softmax accumulation; cache
 position ``i*bs + off`` masking is identical because block ``i`` holds
-logical positions [i*bs, (i+1)*bs).
+logical positions [i*bs, (i+1)*bs).  Its grid is (B, blocks): one step
+reads a whole (bs, Kv, hd) pool block and covers every kv head.
 """
 from __future__ import annotations
 
@@ -29,8 +30,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels.compat import TPUCompilerParams
 
 NEG = -1e30
 
@@ -102,7 +101,7 @@ def decode_attention(q, k, v, length, *, window: int = 0, bs: int = 512,
             pltpu.VMEM((G,), jnp.float32),
             pltpu.VMEM((G,), jnp.float32),
         ],
-        compiler_params=TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(length, q, k, v)
@@ -110,21 +109,16 @@ def decode_attention(q, k, v, length, *, window: int = 0, bs: int = 512,
 
 # ---------------------------------------------------------------- paged
 def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                  acc_ref, m_ref, l_ref, *, bs: int, ns: int, window: int,
-                  scale: float):
+                  acc_ref, m_ref, l_ref, *, bs: int, ns: int, Kv: int,
+                  window: int, scale: float):
     b = pl.program_id(0)
-    isb = pl.program_id(2)
+    isb = pl.program_id(1)
 
     @pl.when(isb == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, NEG)
         l_ref[...] = jnp.zeros_like(l_ref)
-
-    q = q_ref[0, 0].astype(jnp.float32)             # (G, hd)
-    k = k_ref[0, :, 0].astype(jnp.float32)          # (bs, hd)
-    v = v_ref[0, :, 0].astype(jnp.float32)          # (bs, hd)
-    s = (q @ k.T) * scale                            # (G, bs)
 
     length = len_ref[b]
     # windowed variant: the grid only walks the trailing-window blocks,
@@ -135,21 +129,28 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     mask = k_pos < length
     if window:
         mask = mask & (k_pos >= length - window)
-    s = jnp.where(mask, s, NEG)
 
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-    p = jnp.exp(s - m_new[:, None])
-    alpha = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1)
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + p @ v
-    m_ref[...] = m_new
+    # one grid step holds the whole (bs, Kv, hd) pool block: walk its kv
+    # heads statically, each a (G, hd) x (bs, hd) tile on the MXU
+    for g in range(Kv):
+        q = q_ref[0, g].astype(jnp.float32)          # (G, hd)
+        k = k_ref[0, :, g, :].astype(jnp.float32)    # (bs, hd)
+        v = v_ref[0, :, g, :].astype(jnp.float32)    # (bs, hd)
+        s = jnp.where(mask, (q @ k.T) * scale, NEG)  # (G, bs)
+
+        m_prev = m_ref[g]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
+        p = jnp.exp(s - m_new[:, None])
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[g] = l_ref[g] * alpha + jnp.sum(p, axis=1)
+        acc_ref[g] = acc_ref[g] * alpha[:, None] + p @ v
+        m_ref[g] = m_new
 
     @pl.when(isb == ns - 1)
     def _done():
-        o_ref[0, 0] = (acc_ref[...] /
-                       jnp.maximum(l_ref[...], 1e-20)[:, None]
-                       ).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] /
+                    jnp.maximum(l_ref[...], 1e-20)[..., None]
+                    ).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
@@ -167,7 +168,9 @@ def paged_decode_attention(q, k_pool, v_pool, table, length, *,
     The block table and lengths ride scalar prefetch: the k/v index maps
     dereference ``table[b, i]`` so each grid step DMAs exactly the one
     block it needs — the paged gather costs no extra HBM traffic over the
-    dense kernel.
+    dense kernel.  Grid (B, ns): a step reads one whole ``(bs, Kv, hd)``
+    pool block, whose trailing two dims are the pool's own — the TPU
+    block-shape rule — and covers every kv head of that sequence.
 
     With ``window`` > 0 only the trailing ``window`` cache positions are
     attended (sliding-window decode): the grid's sequence axis shrinks to
@@ -188,35 +191,34 @@ def paged_decode_attention(q, k_pool, v_pool, table, length, *,
     # a trailing window can straddle
     ns = MB if not window else min(MB, (window + bs - 2) // bs + 1)
 
-    def blk(b, g, i, tbl, ln):
+    def blk(b, i, tbl, ln):
         if window:
             i = jnp.minimum(jnp.maximum(ln[b] - window, 0) // bs + i, MB - 1)
-        return (tbl[b, i], 0, g, 0)
+        return (tbl[b, i], 0, 0, 0)
 
-    kern = functools.partial(_paged_kernel, bs=bs, ns=ns, window=window,
-                             scale=scale)
+    kern = functools.partial(_paged_kernel, bs=bs, ns=ns, Kv=Kv,
+                             window=window, scale=scale)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, Kv, ns),
+        grid=(B, ns),
         in_specs=[
-            pl.BlockSpec((1, 1, G, hd),
-                         lambda b, g, i, tbl, ln: (b, g, 0, 0)),
-            pl.BlockSpec((1, bs, 1, hd), blk),
-            pl.BlockSpec((1, bs, 1, hd), blk),
+            pl.BlockSpec((1, Kv, G, hd), lambda b, i, tbl, ln: (b, 0, 0, 0)),
+            pl.BlockSpec((1, bs, Kv, hd), blk),
+            pl.BlockSpec((1, bs, Kv, hd), blk),
         ],
-        out_specs=pl.BlockSpec((1, 1, G, hd),
-                               lambda b, g, i, tbl, ln: (b, g, 0, 0)),
+        out_specs=pl.BlockSpec((1, Kv, G, hd),
+                               lambda b, i, tbl, ln: (b, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((G, hd), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
+            pltpu.VMEM((Kv, G, hd), jnp.float32),
+            pltpu.VMEM((Kv, G), jnp.float32),
+            pltpu.VMEM((Kv, G), jnp.float32),
         ],
     )
     return pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Kv, G, hd), q.dtype),
-        compiler_params=TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(table, length, q, k_pool, v_pool)

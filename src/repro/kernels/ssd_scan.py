@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import TPUCompilerParams
-
 _NEG = -1e30
 
 
@@ -118,7 +116,7 @@ def ssd_chunk_scan(q, k, v, log_a, log_i, *, chunk: int = 128,
             pltpu.VMEM((1, N), jnp.float32),
             pltpu.VMEM((1, 1), jnp.float32),
         ],
-        compiler_params=TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qb, kb, vb, lab, lib)

@@ -33,14 +33,13 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import TPUCompilerParams
-
 NEG = -1e30
 
 
 def _kernel(len_ref, q_ref, k_ref, v_ref, tm_ref, qp_ref, o_ref,
             acc_ref, m_ref, l_ref, *, bs: int, ns: int, N: int, C: int,
             G: int, hd: int, window: int, scale: float):
+    b = pl.program_id(0)
     isb = pl.program_id(2)
 
     @pl.when(isb == 0)
@@ -54,7 +53,7 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, tm_ref, qp_ref, o_ref,
     v = v_ref[0, 0].astype(jnp.float32)              # (BS, hd)
     s = (q @ k.T) * scale                            # (G*N, BS)
 
-    base = len_ref[0] - (C - N)                      # tree start in the cache
+    base = len_ref[b, 0] - (C - N)                   # tree start in the cache
     k_pos = isb * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)[0]
     in_cache = k_pos < base                          # (BS,)
     # tree region [base, base+C): column j of the ancestor mask governs
@@ -66,7 +65,7 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, tm_ref, qp_ref, o_ref,
     tree_cols = (tm_ref[...].astype(jnp.float32) @ onehot) > 0.5   # (N, BS)
     mask = in_cache[None, :] | tree_cols             # (N, BS)
     if window:
-        qp = qp_ref[0]                               # (N,)
+        qp = qp_ref[0, 0]                            # (N,)
         mask = mask & (k_pos[None, :] > qp[:, None] - window)
     mask = jnp.broadcast_to(mask[None], (G, N, bs)).reshape(G * N, bs)
     s = jnp.where(mask, s, NEG)
@@ -119,12 +118,16 @@ def tree_verify_attention(q, k, v, length, tree_mask, q_pos, *,
         kern,
         grid=(B, Kv, ns),
         in_specs=[
-            pl.BlockSpec((1,), lambda b, g, i: (b,), memory_space=pltpu.SMEM),
+            # ``length`` rides whole in SMEM as (B, 1) and each step reads
+            # its own row; ``q_pos`` rides as (B, 1, N).  Both keep every
+            # block's trailing two dims equal to the array's (the TPU
+            # tiling rule), also when vmap prepends a batch axis
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, 1, G, N, hd), lambda b, g, i: (b, g, 0, 0, 0)),
             pl.BlockSpec((1, 1, bs, hd), lambda b, g, i: (b, g, i, 0)),
             pl.BlockSpec((1, 1, bs, hd), lambda b, g, i: (b, g, i, 0)),
             pl.BlockSpec((N, C), lambda b, g, i: (0, 0)),
-            pl.BlockSpec((1, N), lambda b, g, i: (b, 0)),
+            pl.BlockSpec((1, 1, N), lambda b, g, i: (b, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, G, N, hd), lambda b, g, i: (b, g, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, Kv, G, N, hd), q.dtype),
@@ -133,7 +136,8 @@ def tree_verify_attention(q, k, v, length, tree_mask, q_pos, *,
             pltpu.VMEM((G * N,), jnp.float32),
             pltpu.VMEM((G * N,), jnp.float32),
         ],
-        compiler_params=TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(length, q, k, v, tree_mask.astype(jnp.int32), q_pos.astype(jnp.int32))
+    )(length.reshape(B, 1), q, k, v, tree_mask.astype(jnp.int32),
+      q_pos.astype(jnp.int32).reshape(B, 1, N))

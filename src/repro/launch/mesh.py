@@ -7,24 +7,38 @@ cloud/edge pool boundary for the collaborative engine).
 
 Functions, not module constants: importing this module must not touch jax
 device state (the dry-run sets XLA_FLAGS before first jax init).
+
+Every mesh is built by ``make_mesh`` with ``AxisType.Auto`` axes: the
+serving path places arrays with ``NamedSharding``s and
+``with_sharding_constraint`` and lets the partitioner propagate the rest,
+which ``jax.make_mesh``'s default Explicit axes refuse.
 """
 from __future__ import annotations
 
 import math
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, *, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``; ``devices`` defaults to
+    the local devices."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 2, model: int = 2):
     """Tiny mesh for CPU tests (requires xla_force_host_platform_device_count
     >= data*model in the test process)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def _balanced_factor(rem: int, k: int) -> int:
@@ -74,7 +88,7 @@ def parse_mesh_arg(spec: str):
     if rem != 1:
         raise ValueError(f"mesh spec {spec!r} does not use all {ndev} "
                          f"local devices (shape {tuple(sizes)})")
-    return jax.make_mesh(tuple(sizes), tuple(names))
+    return make_mesh(sizes, names)
 
 
 def data_axes(mesh) -> tuple:

@@ -127,8 +127,10 @@ from repro.core.engine import CollaborativeEngine
 from repro.core.policy import (POLICIES, ThresholdPolicy, make_policy,
                                policy_from_legacy)
 from repro.core.scheduler import BatchedEngine
-from repro.core.traffic import (bursty_arrivals, poisson_arrivals, replay)
+from repro.core.traffic import (WallClock, bursty_arrivals,
+                                poisson_arrivals, replay)
 from repro.data import SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import Model
 
 
@@ -258,6 +260,7 @@ def main():
                          "docstring's 'Running on a mesh' section")
     ap.add_argument("--reduced", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     e_cfg = get_config(args.edge)
     c_cfg = get_config(args.cloud)
@@ -388,7 +391,8 @@ def main():
                   f"preemptions={stats.get('preemptions', 0)} "
                   f"swaps={stats.get('kv_swaps', 0)}")
     if "ttft_p50_ms" in stats:
-        unit = "virtual ms" if args.arrival != "none" else "ms"
+        # VirtualClock latencies are modelled, not measured
+        unit = "ms" if isinstance(eng.clock, WallClock) else "virtual ms"
         print(f"latency ({unit}): "
               f"ttft p50={stats['ttft_p50_ms']:.1f} "
               f"p99={stats['ttft_p99_ms']:.1f} "

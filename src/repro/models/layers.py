@@ -8,10 +8,14 @@ Conventions:
 """
 from __future__ import annotations
 
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
+
+from repro import runtime
 
 
 # ----------------------------------------------------------------- init utils
@@ -327,8 +331,16 @@ def paged_decode_attention_block(p, x, k_pool, v_pool, table, pos, cfg, *,
     length = pos + 1
     win = cfg.sliding_window
     if backend == "kernel" or (backend == "auto" and not ops.on_cpu()):
-        out = ops.paged_decode_attention(qh, k_pool, v_pool, table, length,
-                                         window=win)
+        # under a mesh each shard runs the kernel on its own slots and kv
+        # heads; pool blocks stay whole over the data axes, since the
+        # table holds global block ids
+        dp, mp = runtime.kernel_axes(B, Kv)
+        attn = runtime.shard_kernel(
+            functools.partial(ops.paged_decode_attention, window=win),
+            in_specs=(P(dp, mp), P(None, None, mp), P(None, None, mp),
+                      P(dp), P(dp)),
+            out_specs=P(dp, mp))
+        out = attn(qh, k_pool, v_pool, table, length)
     else:
         out = ref.paged_decode_attention_ref(qh, k_pool, v_pool, table,
                                              length, window=win)
@@ -373,10 +385,16 @@ def extend_attention(p, x, cache_k, cache_v, pos, cfg, *, window: int = 0,
             # (T, Smax) mask; CPU keeps the jnp masked-mha path below
             G = H // Kv
             qh = jnp.transpose(q.reshape(B, T, Kv, G, hd), (0, 2, 3, 1, 4))
-            out = ops.tree_verify_attention(
-                qh, jnp.moveaxis(cache_k, 2, 1), jnp.moveaxis(cache_v, 2, 1),
-                jnp.broadcast_to(pos, (B,)), block_mask,
-                jnp.broadcast_to(q_pos, (B, T)), window=window)
+            dp, mp = runtime.kernel_axes(B, Kv)
+            attn = runtime.shard_kernel(
+                functools.partial(ops.tree_verify_attention, window=window),
+                in_specs=(P(dp, mp), P(dp, mp), P(dp, mp), P(dp), P(),
+                          P(dp)),
+                out_specs=P(dp, mp))
+            out = attn(qh, jnp.moveaxis(cache_k, 2, 1),
+                       jnp.moveaxis(cache_v, 2, 1),
+                       jnp.broadcast_to(pos, (B,)), block_mask,
+                       jnp.broadcast_to(q_pos, (B, T)))
             out = jnp.transpose(out, (0, 3, 1, 2, 4)).astype(x.dtype)
             return out.reshape(B, T, H * hd) @ p["wo"], cache_k, cache_v
         off = block_mask.shape[1] - T            # tree nodes already cached

@@ -99,8 +99,6 @@ def moe_block_sharded(p, x, cfg, mesh, dp_axes, ep_axis: str):
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.runtime import shard_map
-
     B, S, d = x.shape
     E, k = cfg.num_experts, cfg.top_k
     n_ep = mesh.shape[ep_axis]
@@ -152,7 +150,7 @@ def moe_block_sharded(p, x, cfg, mesh, dp_axes, ep_axis: str):
         return combined.reshape(Bl, Sl, d).astype(xl.dtype), aux
 
     dp = tuple(dp_axes)
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(), P(ep_axis, None, None), P(ep_axis, None, None),
                   P(ep_axis, None, None), P(dp, None, None)),

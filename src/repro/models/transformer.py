@@ -42,8 +42,9 @@ def init_params(rng, cfg):
             blk["mlp"] = L.init_mlp(rr[1], cfg, dtype)
         return blk
 
-    blocks = jax.tree.map(lambda *xs: jnp.stack(xs),
-                          *[one_block(r[i]) for i in range(cfg.num_layers)])
+    # one vmapped block init: the same values as a per-layer loop, built
+    # straight into the stacked (L, ...) leaves with no per-layer copies
+    blocks = jax.vmap(one_block)(jnp.stack(r[:cfg.num_layers]))
     params = {
         "embed": L.init_embedding(r[-3], cfg.vocab_size, cfg.d_model, dtype),
         "blocks": blocks,

@@ -7,42 +7,12 @@ can find it at trace time.
 from __future__ import annotations
 
 import contextlib
-import inspect
 import threading
 from typing import Optional, Tuple
 
 from repro.analysis import hot_path
 
 _state = threading.local()
-
-
-# ---------------------------------------------------------------- shard_map
-def _resolve_shard_map():
-    """Locate shard_map across JAX versions: newest exports it from the
-    top-level ``jax`` namespace, older releases from
-    ``jax.experimental.shard_map``."""
-    import jax
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:
-        from jax.experimental.shard_map import shard_map as fn
-    return fn
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-compatible ``shard_map`` wrapper.
-
-    Newer JAX calls the replication-checking flag ``check_vma``; older
-    releases call it ``check_rep``.  Model code imports this shim so the
-    explicitly-distributed layers (MoE expert parallelism) run on either.
-    """
-    fn = _resolve_shard_map()
-    params = inspect.signature(fn).parameters
-    kw = {"mesh": mesh, "in_specs": in_specs, "out_specs": out_specs}
-    if "check_vma" in params:
-        kw["check_vma"] = check_vma
-    elif "check_rep" in params:
-        kw["check_rep"] = check_vma
-    return fn(f, **kw)
 
 
 def current_mesh():
@@ -86,6 +56,35 @@ def _dp_count(mesh) -> int:
     return n
 
 
+def kernel_axes(batch: int, heads: int):
+    """Mesh axes a Pallas call's operands split over: ``(dp, mp)``, the
+    data axes when ``batch`` divides over them and ``"model"`` when
+    ``heads`` divides over it, else None for that side.  ``(None, None)``
+    outside a mesh context."""
+    mesh = current_mesh()
+    if mesh is None:
+        return None, None
+    dp = data_axes()
+    n_dp = _dp_count(mesh)
+    mp = model_axis()
+    m = mesh.shape.get(mp, 1)
+    return (dp if n_dp > 1 and batch % n_dp == 0 else None,
+            mp if m > 1 and heads % m == 0 else None)
+
+
+def shard_kernel(kernel, in_specs, out_specs):
+    """Run a Pallas kernel per shard inside a mesh context.  A Mosaic call
+    cannot be partitioned automatically, so under a mesh it runs in
+    ``jax.shard_map`` with the given specs; operands sharded otherwise are
+    resharded to them at the boundary.  Identity off-mesh."""
+    mesh = current_mesh()
+    if mesh is None:
+        return kernel
+    import jax
+    return jax.shard_map(kernel, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
+
+
 @hot_path
 def gather_wave(*arrays):
     """All-gather a grouped escalation wave across the data axes in ONE
@@ -116,8 +115,8 @@ def gather_wave(*arrays):
     out_specs = tuple(P(*([None] * a.ndim)) for a in arrays)
     # check_vma=False: the all-gather's output IS replicated over the data
     # axes, but the static replication checker cannot infer that
-    out = shard_map(gather, mesh=mesh, in_specs=in_specs,
-                    out_specs=out_specs, check_vma=False)(*arrays)
+    out = jax.shard_map(gather, mesh=mesh, in_specs=in_specs,
+                        out_specs=out_specs, check_vma=False)(*arrays)
     return out if len(arrays) > 1 else out[0]
 
 

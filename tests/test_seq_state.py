@@ -344,7 +344,11 @@ def test_paged_decode_backend_dispatch_parity():
                                rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(lg_kern), np.asarray(lg_gather),
                                rtol=1e-4, atol=1e-4)
-    for key in ("k", "v", "pos"):
+    # both paths write the same K/V projection, computed in two float32
+    # programs whose fusions round differently in the last bits
+    for key in ("k", "v"):
         np.testing.assert_allclose(
             np.asarray(c_ref[key], np.float32),
-            np.asarray(c_gather[key], np.float32), rtol=1e-6)
+            np.asarray(c_gather[key], np.float32), rtol=1e-6, atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(c_ref["pos"]),
+                                  np.asarray(c_gather["pos"]))

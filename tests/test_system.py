@@ -87,11 +87,12 @@ import jax, jax.numpy as jnp
 from repro import runtime
 from repro.configs import get_config
 from repro.launch import sharding as SH
+from repro.launch.mesh import make_host_mesh
 from repro.models import Model
 from repro.training.optimizer import AdamW, AdamWState
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_host_mesh(data=4, model=2)
 cfg = get_config("olmoe-1b-7b").reduced().replace(num_experts=4, top_k=2)
 model = Model(cfg)
 params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))
@@ -114,7 +115,7 @@ with runtime.mesh_context(mesh):
 from repro.launch.hlo_cost import cost_analysis_dict
 print("COMPILED_OK", cost_analysis_dict(compiled).get("flops", 0) > 0)
 """
-    env = dict(os.environ, PYTHONPATH=SRC)
+    env = dict(os.environ, PYTHONPATH=SRC, JAX_PLATFORMS="cpu")
     out = subprocess.run([sys.executable, "-c", script], env=env,
                          capture_output=True, text=True, timeout=600)
     assert "COMPILED_OK True" in out.stdout, out.stderr[-2000:]
