@@ -1,0 +1,21 @@
+"""Model step: device milliseconds of the edge tick program per decode
+step it ran, in the traced sub-window.  Steps are counted as the paged
+decode kernel's calls over the edge's layers (one call per layer per
+step)."""
+from bench import trace as TR
+
+TICK = r"jit_chunk"
+KERNEL = r"paged_decode"
+
+
+def read(run):
+    if run.trace is None:
+        return None
+    tick_ns = calls = 0.0
+    for d in run.trace["devices"].values():
+        tick_ns += TR.matching(d["modules"], TICK)[0]
+        calls += TR.matching(d["ops"], KERNEL)[1]
+    steps = calls / run.cfg["edge"]["num_layers"]
+    if not tick_ns or not steps:
+        return None
+    return tick_ns / steps / 1e6
