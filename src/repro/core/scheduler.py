@@ -205,6 +205,10 @@ class BatchedEngine:
       * ``kv_blocks``: total pool blocks incl. the trap (paged).  Default
         sizes the pool to the dense worst case; give a smaller pool to cap
         KV memory — admission is deferred when it runs full.
+
+    ``spans``: a ``core/spans.py`` ``SpanLog`` that records each drain-loop
+    iteration as an ``engine.tick`` span with its steps and counters, or
+    None (the default: nothing is recorded).
     """
 
     def __init__(self, edge_model, cloud_model, *, batch_size: int = 8,
@@ -223,7 +227,7 @@ class BatchedEngine:
                  spec_mode: Optional[str] = None,
                  spec_tree_width: Optional[int] = None,
                  spec_exit_layer: Optional[int] = None,
-                 mesh=None, adaptation=None):
+                 mesh=None, adaptation=None, spans=None):
         if batch_size < 1:
             raise ValueError(f"batch_size must be >= 1, got {batch_size}")
         if tick_tokens < 1:
@@ -288,6 +292,7 @@ class BatchedEngine:
         self.adaptation = adaptation
         if adaptation is not None:
             adaptation.bind(edge_model)
+        self.spans = spans
         # speculation lane: engine kwarg > policy attribute > linear.  A
         # model family the requested lane cannot serve falls back to the
         # linear tape; the EFFECTIVE mode is what stats()["spec_mode"]
@@ -459,9 +464,16 @@ class BatchedEngine:
                                 "swaps": 0, "defers": 0}
                         for r in self._queue}
         stop = jnp.int32(-1 if self.stop_token is None else self.stop_token)
+        # one engine.tick span per iteration; its steps are boundary marks
+        # (sp.mark closes the previous step), so the loop keeps its shape
+        sp = self.spans
+        if sp is not None:
+            sp.bind(clock)
 
         while self._queue or self._swapped or any(s.req is not None
                                                   for s in slots):
+            if sp is not None:
+                sp.begin("engine.tick")
             # ---- online-adaptation hot-swap point: BETWEEN ticks, the
             # loop offers the current edge weights for replacement.  The
             # swap is a pure pytree rebind — same treedef/shapes/dtypes —
@@ -472,6 +484,8 @@ class BatchedEngine:
                 if swapped_p is not None:
                     edge_params = swapped_p
                     state.rebind(edge_params)
+            if sp is not None:
+                sp.mark("sched.admit")
             free = [b for b in range(B) if slots[b].req is None]
             wave: set = set()       # slots admitted/resumed this wave
             stalled = False
@@ -551,6 +565,7 @@ class BatchedEngine:
                     if hit is not None:
                         ev = self._events[r.rid]
                         ev["first_token_ms"] = ev["retire_ms"] = now
+                        ev["first_token_ready_ms"] = now
                         ev["path"], ev["tokens"] = "cache", len(hit)
                         results[r.rid] = RequestTrace("cache",
                                                       tokens=list(hit))
@@ -675,6 +690,7 @@ class BatchedEngine:
                             self.prefill_chunk)
                     else:
                         clock.on_prefill(r.prompt.size - 1)
+                        self._events[r.rid]["prefill_done_ms"] = now
                         bs.append(b)
                         lasts.append([[int(r.prompt[-1])]])
                         news.append(r.max_new)
@@ -717,15 +733,23 @@ class BatchedEngine:
             # ---- advance chunked prefills: one detached chunk per job per
             # tick, interleaved with the batch's decode; a finished job
             # lands its cache (finalize) and arms the slot for decode
+            if sp is not None:
+                sp.mark("prefill.advance", jobs=len(self._prefill_jobs))
             for b in list(self._prefill_jobs):
                 job = self._prefill_jobs[b]
                 before = job["done"]
                 finished = self.edge.advance_prefill(edge_params, job)
                 clock.on_prefill(job["done"] - before)
+                if sp is not None:
+                    sp.count(tokens=job["done"] - before)
                 if finished:
                     state.finalize(b, job["cache"])
                     del self._prefill_jobs[b]
                     r = slots[b].req
+                    ev = self._events[r.rid]
+                    ev["prefill_done_ms"] = clock.now()
+                    ev["prefill_chunks"] = -(-job["done"]
+                                             // self.prefill_chunk)
                     tok = tok.at[b, 0, 0].set(int(r.prompt[-1]))
                     steps = steps.at[b].set(r.max_new)
                     unc = unc.at[b].set(0.0)
@@ -746,11 +770,15 @@ class BatchedEngine:
                         f"{rid0} even with an empty batch (its blocks + "
                         "outstanding reservation exceed the pool); raise "
                         "kv_blocks")
+                if sp is not None:
+                    sp.finish()
                 if self._queue:
                     # nothing in flight and every queued arrival is in the
                     # future: jump/sleep the clock to the next arrival
                     clock.wait_until(float(self._queue[0].at))
                 continue            # all cache hits / cloud completions
+            if sp is not None:
+                sp.mark("kv.flush")
             state.flush()
 
             # ---- one batched decode tick (pow2-bucketed step count: the
@@ -760,9 +788,15 @@ class BatchedEngine:
             # repro-lint: ok(R1, steps_h is the numpy host mirror - no device pull)
             live = int(steps_h[occupied].max())
             if live <= 0:
+                if sp is not None:
+                    sp.finish()
                 continue            # every occupied slot is mid-prefill
             n = pow2_steps(min(self.tick_tokens, live), self.tick_tokens)
+            if sp is not None:
+                sp.mark("kv.prepare_tick")
             state.prepare_tick(occupied, steps_h, n)
+            if sp is not None:
+                sp.mark("edge.dispatch", n_steps=n)
             rng, r = jax.random.split(rng)
             state.caches, tok, steps, unc, toks, actives = self.edge._chunk(
                 edge_params, state.caches, tok, steps, unc, r, stop,
@@ -772,8 +806,13 @@ class BatchedEngine:
             # THE host readback: one batched explicit pull per tick covers
             # retirement (steps/unc), the emitted streams (toks/actives)
             # and the carry mirror (tok == last scan emission)
+            if sp is not None:
+                sp.mark("edge.readback")
             steps_d, unc_d, toks_h, act_h = jax.device_get(  # repro-lint: ok(R1, the single batched per-tick device pull)
                 (steps, unc, toks, actives))
+            t_ready = clock.now()
+            if sp is not None:
+                sp.mark("sched.retire")
             steps_h = np.array(steps_d)     # device_get views are
             unc_h = np.array(unc_d)         # read-only; mirrors mutate
             tok_h = np.array(toks_h[-1])
@@ -781,9 +820,12 @@ class BatchedEngine:
                 new = [int(t) for t, a in zip(toks_h[:, b], act_h[:, b])
                        if a]
                 if new and not slots[b].tokens:
-                    # tick-granular first-token stamp (end of the emitting
-                    # tick); escalated requests are re-stamped in _finish
-                    self._events[slots[b].req.rid]["first_token_ms"] = t_tick
+                    # tick-granular first-token stamps: the emitting tick's
+                    # dispatch, and its readback (the host holds the token);
+                    # escalated requests are re-stamped in _finish
+                    ev = self._events[slots[b].req.rid]
+                    ev["first_token_ms"] = t_tick
+                    ev["first_token_ready_ms"] = t_ready
                 slots[b].tokens.extend(new)
 
             # ---- retire finished slots; the policy names each one's action
@@ -858,6 +900,12 @@ class BatchedEngine:
                             [g[0] for g in grp], [g[1] for g in grp], r):
                         self._finish(results, req, tr,
                                      t_first=t_esc + clock.step_ms)
+            if sp is not None:
+                sp.finish(slots_occupied=len(occupied),
+                          slots_decoding=sum(b not in self._prefill_jobs
+                                             for b in occupied),
+                          slot_steps_active=np.count_nonzero(act_h),
+                          slot_steps_run=n * B)
 
         self._kv_stats["kv_peak_bytes"] = state.peak_bytes
         self._kv_stats["kv_capacity_bytes"] = state.capacity_bytes
@@ -923,14 +971,16 @@ class BatchedEngine:
         """Complete ``req``: stamp lifecycle events, fire policy feedback,
         warm the cache, resolve followers.  ``t_first`` overrides the
         first-token stamp (escalations/cloud lanes — their client stream
-        starts with the regeneration, not the discarded edge decode)."""
+        starts with the regeneration, not the discarded edge decode); the
+        host holds that stream only from the wave's return, now."""
         now = self.clock.now()
         ev = self._events.setdefault(
             req.rid, {"submit_ms": now, "swaps": 0, "defers": 0})
         if t_first is not None:
             ev["first_token_ms"] = t_first
+            ev["first_token_ready_ms"] = now
         elif "first_token_ms" not in ev:
-            ev["first_token_ms"] = now
+            ev["first_token_ms"] = ev["first_token_ready_ms"] = now
         ev["retire_ms"] = now
         ev["path"] = tr.path
         ev["tokens"] = len(tr.tokens) if tr.tokens else 0
@@ -980,6 +1030,7 @@ class BatchedEngine:
             fev = self._events.setdefault(
                 f.rid, {"submit_ms": now, "swaps": 0, "defers": 0})
             fev.setdefault("first_token_ms", now)
+            fev.setdefault("first_token_ready_ms", now)
             fev["retire_ms"] = now
             fev["path"], fev["tokens"] = "cache", ev["tokens"]
             results[f.rid] = RequestTrace(
@@ -1147,7 +1198,11 @@ class BatchedEngine:
     def events(self) -> Dict[int, dict]:
         """Per-request lifecycle events of the last ``run`` (rid ->
         submit/admit/first-token/retire stamps in clock ms, swap + defer
-        counts, path, token count)."""
+        counts, path, token count).  Also ``prefill_done_ms`` (a chunked
+        prefill's finalize, with ``prefill_chunks``; ``admit_ms`` for a
+        whole-prompt one) and ``first_token_ready_ms`` (the readback or
+        wave return that put the first token on the host; ``first_token_ms``
+        is the emitting tick's dispatch)."""
         return self._events
 
     def stats(self) -> Dict[str, Any]:
