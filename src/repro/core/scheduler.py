@@ -801,6 +801,7 @@ class BatchedEngine:
             state.caches, tok, steps, unc, toks, actives = self.edge._chunk(
                 edge_params, state.caches, tok, steps, unc, r, stop,
                 n_steps=n)
+            state.advance(n)
             clock.on_steps(n)
             t_tick = clock.now()
             # THE host readback: one batched explicit pull per tick covers
@@ -905,7 +906,8 @@ class BatchedEngine:
                           slots_decoding=sum(b not in self._prefill_jobs
                                              for b in occupied),
                           slot_steps_active=np.count_nonzero(act_h),
-                          slot_steps_run=n * B)
+                          slot_steps_run=n * B,
+                          **state.attn_blocks(n, act_h))
 
         self._kv_stats["kv_peak_bytes"] = state.peak_bytes
         self._kv_stats["kv_capacity_bytes"] = state.capacity_bytes

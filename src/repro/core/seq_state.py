@@ -9,9 +9,10 @@ same slot/tick/escalation machinery:
 
   * ``SequenceState`` — the host-side slot-state owner: ``admit`` (prefill
     + capacity reservation), ``flush`` (batched device writes),
-    ``prepare_tick`` (per-tick capacity growth), ``retire`` (free), and the
-    ``peak_bytes`` / ``capacity_bytes`` / ``stats`` accounting the
-    benchmarks read.  One implementation per layout:
+    ``prepare_tick`` (per-tick capacity growth), ``advance`` (the host
+    mirror of ``pos`` after a tick), ``retire`` (free), and the
+    ``peak_bytes`` / ``capacity_bytes`` / ``stats`` / ``attn_blocks``
+    accounting the benchmarks and spans read.  One implementation per layout:
 
       - ``DenseKV``    — stacked per-slot caches padded to a common
         ``slot_len`` (the parity oracle).
@@ -308,6 +309,15 @@ class SequenceState:
     def prepare_tick(self, occupied, steps_h, n: int):
         """Grow capacity to cover this tick's real decode steps."""
 
+    def advance(self, n: int):
+        """The tick program ran ``n`` steps, each moving every slot's
+        ``pos`` on by one (layouts that mirror ``pos`` on the host)."""
+
+    def attn_blocks(self, n: int, actives) -> Dict[str, int]:
+        """Counts of the paged decode kernel's walk over the tick that
+        ``advance(n)`` just mirrored; none on layouts without a table."""
+        return {}
+
     def retire(self, b: int):
         """Release slot ``b``'s capacity."""
 
@@ -485,6 +495,9 @@ class PagedKV(SequenceState):
         self._block_bytes = (self.caches["k"].nbytes +
                              self.caches["v"].nbytes) // num_blocks
         self._len = [0] * batch     # real cache entries written per slot
+        # host mirror of the device's caches["pos"]: set where flush sets
+        # it, moved on by advance; no device pull
+        self.pos_h = np.zeros((batch,), np.int64)
         self._commit = [0] * batch  # blocks reserved for future growth
         self._entries: List[Optional[np.ndarray]] = [None] * batch  # prompts
         self._stale: set = set()    # retired slots awaiting a trap row
@@ -823,7 +836,24 @@ class PagedKV(SequenceState):
             jnp.asarray(np.stack(rows)))
         self.caches["pos"] = self.caches["pos"].at[ii].set(
             jnp.asarray(poss, jnp.int32))
+        self.pos_h[idx] = poss
         self._pend, self._stale = [], set()
+
+    def advance(self, n: int):
+        self.pos_h += n
+
+    def attn_blocks(self, n: int, actives) -> Dict[str, int]:
+        """Over the ``n`` steps: the table entries a walk of every block
+        would read (``attn_blocks_table``); the blocks the kernel walks,
+        ``min(cdiv(pos + 1, bs), MB)`` per slot and step
+        (``attn_blocks_visited``); and those of the slots that emitted in
+        that step, ``actives`` (n, B) bool (``attn_blocks_live``)."""
+        pos = self.pos_h - n + np.arange(n)[:, None]            # (n, B)
+        walk = np.minimum(-(-(pos + 1) // self.block_size),
+                          self.max_blocks)
+        return {"attn_blocks_table": walk.size * self.max_blocks,
+                "attn_blocks_visited": int(walk.sum()),
+                "attn_blocks_live": int(walk[actives].sum())}
 
     @hot_path
     def prepare_tick(self, occupied, steps_h, n: int):

@@ -15,11 +15,12 @@ VMEM scratch.
 ``paged_decode_attention`` is the paged-KV twin (vLLM-style): K/V live in
 ONE (NB, bs, Kv, hd) block pool shared by all sequences, and each
 sequence's logical block ``i`` is found through a scalar-prefetched block
-table — the BlockSpec index map reads ``table[b, i]`` to aim the next DMA,
+table — the BlockSpec index maps read ``table[b, i]`` to aim the DMAs,
 so the gather never materializes.  Same online-softmax accumulation; cache
 position ``i*bs + off`` masking is identical because block ``i`` holds
-logical positions [i*bs, (i+1)*bs).  Its grid is (B, blocks): one step
-reads a whole (bs, Kv, hd) pool block and covers every kv head.
+logical positions [i*bs, (i+1)*bs).  Each sequence walks only the blocks
+below its length, a group of blocks per grid step: one step reads several
+whole (bs, Kv, hd) pool blocks and covers every kv head.
 """
 from __future__ import annotations
 
@@ -108,45 +109,87 @@ def decode_attention(q, k, v, length, *, window: int = 0, bs: int = 512,
 
 
 # ---------------------------------------------------------------- paged
-def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                  acc_ref, m_ref, l_ref, *, bs: int, ns: int, Kv: int,
-                  window: int, scale: float):
-    b = pl.program_id(0)
-    isb = pl.program_id(1)
+# VMEM for one call's K and V blocks, both double-buffered; the number of
+# blocks fetched per DMA wait is what fits in it (``_group_blocks``)
+_KV_VMEM_BYTES = 8 * 2**20
 
-    @pl.when(isb == 0)
+
+def _group_blocks(bs: int, Kv: int, hd: int, dtype, MB: int) -> int:
+    """Pool blocks per group: K and V, two buffers each, of ``(bs, Kv, hd)``
+    blocks laid out in VMEM tiles (second-minor dim padded to the dtype's
+    sublane tile, minor dim to 128 lanes), within ``_KV_VMEM_BYTES``."""
+    item = jnp.dtype(dtype).itemsize
+    sub = 8 * max(4 // item, 1)
+    tile = bs * (-(-Kv // sub) * sub) * (-(-hd // 128) * 128) * item
+    return max(1, min(MB, _KV_VMEM_BYTES // (4 * tile)))
+
+
+def _schedule(table, lo, hi, P: int):
+    """The grid's steps, each one group of up to P blocks of one sequence.
+
+    Sequence b takes ``max(cdiv(hi - lo, P), 1)`` steps in a row (a
+    sequence with no block still takes one, to write its output).  Returns
+    the number of steps; per step (padded to B * cdiv(MB, P)) its sequence
+    and its group within that sequence's walk, (2, S); and the pool block
+    of each of its P group slots, (S * P,).  A slot past hi keeps the
+    block it held one group earlier, so the pipeline fetches nothing new,
+    or in a sequence's first group takes its last live block: a block
+    below the length (where the sequence has one), its positions
+    masked."""
+    B, MB = table.shape
+    S = B * -(-MB // P)
+    steps = jnp.maximum((hi - lo + P - 1) // P, 1)
+    seq = jnp.repeat(jnp.arange(B), steps, total_repeat_length=S)
+    grp = jnp.arange(S) - (jnp.cumsum(steps) - steps)[seq]
+    lo_s, hi_s = lo[seq][:, None], hi[seq][:, None]
+    i = lo_s + grp[:, None] * P + jnp.arange(P)
+    i = jnp.where(i < hi_s, i, jnp.where(i - P >= lo_s, i - P, hi_s - 1))
+    blocks = jnp.take_along_axis(table[seq], jnp.clip(i, 0, MB - 1), axis=1)
+    return steps.sum(), jnp.stack([seq, grp]), blocks.reshape(-1)
+
+
+def _paged_kernel(len_ref, walk_ref, step_ref, blk_ref, q_ref, *refs,
+                  bs: int, MB: int, P: int, Kv: int, window: int,
+                  scale: float):
+    k_refs, v_refs = refs[:P], refs[P:2 * P]
+    o_ref, acc_ref, m_ref, l_ref = refs[2 * P:]
+    t = pl.program_id(0)
+    b, g = step_ref[0, t], step_ref[1, t]
+    length, lo, hi = len_ref[b], walk_ref[0, b], walk_ref[1, b]
+    first = lo + g * P                          # the group's first block
+
+    @pl.when(g == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, NEG)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    length = len_ref[b]
-    # windowed variant: the grid only walks the trailing-window blocks,
-    # starting at logical block sb = max(length - window, 0) // bs
-    sb = jnp.maximum(length - window, 0) // bs if window else 0
-    k_pos = (sb + isb) * bs + \
-        jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)[0]
-    mask = k_pos < length
-    if window:
-        mask = mask & (k_pos >= length - window)
+    @pl.when(first < hi)
+    def _group():
+        k_pos = first * bs + \
+            jax.lax.broadcasted_iota(jnp.int32, (1, P * bs), 1)
+        mask = k_pos < jnp.minimum(length, MB * bs)
+        if window:
+            mask = mask & (k_pos >= length - window)
+        # each block holds every kv head: walk them statically, each a
+        # (G, hd) x (P*bs, hd) tile on the MXU
+        for h in range(Kv):
+            q = q_ref[0, h].astype(jnp.float32)                  # (G, hd)
+            k = jnp.concatenate([r[0, :, h, :].astype(jnp.float32)
+                                 for r in k_refs], axis=0)       # (P*bs, hd)
+            v = jnp.concatenate([r[0, :, h, :].astype(jnp.float32)
+                                 for r in v_refs], axis=0)
+            s = jnp.where(mask, (q @ k.T) * scale, NEG)          # (G, P*bs)
 
-    # one grid step holds the whole (bs, Kv, hd) pool block: walk its kv
-    # heads statically, each a (G, hd) x (bs, hd) tile on the MXU
-    for g in range(Kv):
-        q = q_ref[0, g].astype(jnp.float32)          # (G, hd)
-        k = k_ref[0, :, g, :].astype(jnp.float32)    # (bs, hd)
-        v = v_ref[0, :, g, :].astype(jnp.float32)    # (bs, hd)
-        s = jnp.where(mask, (q @ k.T) * scale, NEG)  # (G, bs)
+            m_prev = m_ref[h]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
+            p = jnp.exp(s - m_new[:, None])
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=1)
+            acc_ref[h] = acc_ref[h] * alpha[:, None] + p @ v
+            m_ref[h] = m_new
 
-        m_prev = m_ref[g]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[g] = l_ref[g] * alpha + jnp.sum(p, axis=1)
-        acc_ref[g] = acc_ref[g] * alpha[:, None] + p @ v
-        m_ref[g] = m_new
-
-    @pl.when(isb == ns - 1)
+    @pl.when(first + P >= hi)                   # the sequence's last step
     def _done():
         o_ref[0] = (acc_ref[...] /
                     jnp.maximum(l_ref[...], 1e-20)[..., None]
@@ -160,54 +203,60 @@ def paged_decode_attention(q, k_pool, v_pool, table, length, *,
 
     q: (B, Kv, G, hd); k_pool/v_pool: (NB, bs, Kv, hd) — the shared block
     pool; table: (B, MB) int32 block table (entry i holds the pool block
-    backing logical positions [i*bs, (i+1)*bs) of that sequence; unused
-    entries may point anywhere allocated-or-trap, their positions being
-    masked); length: (B,) int32 valid cache entries.  Returns
-    (B, Kv, G, hd).
+    backing logical positions [i*bs, (i+1)*bs) of that sequence; entries
+    at or past a sequence's length are never read); length: (B,) int32
+    valid cache entries.  Returns (B, Kv, G, hd).
 
-    The block table and lengths ride scalar prefetch: the k/v index maps
-    dereference ``table[b, i]`` so each grid step DMAs exactly the one
-    block it needs — the paged gather costs no extra HBM traffic over the
-    dense kernel.  Grid (B, ns): a step reads one whole ``(bs, Kv, hd)``
-    pool block, whose trailing two dims are the pool's own — the TPU
-    block-shape rule — and covers every kv head of that sequence.
+    Sequence b walks only the logical blocks that hold positions below
+    ``length[b]``: ``cdiv(min(length, MB*bs), bs)`` of them, so a short
+    sequence in a wide table costs its own length.  The walk goes in
+    groups of P blocks (``_group_blocks``: as many as fit a fixed VMEM
+    budget at these shapes), one grid step each, and the grid holds just
+    the steps the walks need (``_schedule``, a dynamic grid size).  The
+    lengths, the walks and each step's pool blocks, read from the block
+    table, ride scalar prefetch.  The pools come in P times over, one
+    ``(1, bs, Kv, hd)`` block spec per group slot, so the pipeline fetches
+    the next step's 2P blocks while this one computes, and the group is
+    one (G, hd) x (P*bs, hd) tile per kv head on the MXU.  (The blocks
+    are not fetched by hand from an unblocked pool: Mosaic refuses a DMA
+    slice of one block whose (Kv, hd) is not tile-aligned, such as
+    (3, 64).)
 
     With ``window`` > 0 only the trailing ``window`` cache positions are
-    attended (sliding-window decode): the grid's sequence axis shrinks to
-    the few blocks that can overlap the window, and the index maps offset
-    the block-table lookup by the per-sequence start block
-    ``max(length - window, 0) // bs`` — long-context sliding-window
-    serving reads O(window) bytes per step, not O(length).  Blocks the
-    clamp pushes past the table edge read a masked (all-NEG) garbage
-    block, contributing exact zeros to the online softmax.
+    attended (sliding-window decode): the walk starts at block
+    ``max(length - window, 0) // bs``, so long-context sliding-window
+    serving reads O(window) bytes per step, not O(length).
+
+    A sequence with nothing to attend (length 0) gets zeros.
     """
     B, Kv, G, hd = q.shape
     NB, bs, Kv2, hd2 = k_pool.shape
     assert (Kv2, hd2) == (Kv, hd), (k_pool.shape, q.shape)
     MB = table.shape[1]
-    scale = 1.0 / np.sqrt(hd)
+    P = _group_blocks(bs, Kv, hd, k_pool.dtype, MB)
+    # the walk: logical blocks [lo, hi) hold the positions below
+    # min(length, MB*bs) (the trailing window's, when windowed)
+    hi = jnp.minimum((jnp.minimum(length, MB * bs) + bs - 1) // bs, MB)
+    lo = jnp.minimum(jnp.maximum(length - window, 0) // bs, hi) \
+        if window else jnp.zeros_like(hi)
+    n_steps, steps, blocks = _schedule(table, lo, hi, P)
 
-    # sequence-axis grid: every block (full attention) or just the blocks
-    # a trailing window can straddle
-    ns = MB if not window else min(MB, (window + bs - 2) // bs + 1)
+    def group_slot(j):
+        return pl.BlockSpec((1, bs, Kv, hd),
+                            lambda t, ln, wk, st, blk: (blk[t * P + j],
+                                                        0, 0, 0))
 
-    def blk(b, i, tbl, ln):
-        if window:
-            i = jnp.minimum(jnp.maximum(ln[b] - window, 0) // bs + i, MB - 1)
-        return (tbl[b, i], 0, 0, 0)
+    def per_seq(t, ln, wk, st, blk):
+        return (st[0, t], 0, 0, 0)
 
-    kern = functools.partial(_paged_kernel, bs=bs, ns=ns, Kv=Kv,
-                             window=window, scale=scale)
+    slots = [group_slot(j) for j in range(P)]
+    kern = functools.partial(_paged_kernel, bs=bs, MB=MB, P=P, Kv=Kv,
+                             window=window, scale=1.0 / np.sqrt(hd))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, ns),
-        in_specs=[
-            pl.BlockSpec((1, Kv, G, hd), lambda b, i, tbl, ln: (b, 0, 0, 0)),
-            pl.BlockSpec((1, bs, Kv, hd), blk),
-            pl.BlockSpec((1, bs, Kv, hd), blk),
-        ],
-        out_specs=pl.BlockSpec((1, Kv, G, hd),
-                               lambda b, i, tbl, ln: (b, 0, 0, 0)),
+        num_scalar_prefetch=4,
+        grid=(n_steps,),
+        in_specs=[pl.BlockSpec((1, Kv, G, hd), per_seq)] + slots + slots,
+        out_specs=pl.BlockSpec((1, Kv, G, hd), per_seq),
         scratch_shapes=[
             pltpu.VMEM((Kv, G, hd), jnp.float32),
             pltpu.VMEM((Kv, G), jnp.float32),
@@ -219,6 +268,8 @@ def paged_decode_attention(q, k_pool, v_pool, table, length, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Kv, G, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(table, length, q, k_pool, v_pool)
+        name="paged_decode_attention",
+    )(length, jnp.stack([lo, hi]), steps, blocks, q, *[k_pool] * P,
+      *[v_pool] * P)

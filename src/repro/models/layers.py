@@ -300,7 +300,8 @@ def paged_decode_attention_block(p, x, k_pool, v_pool, table, pos, cfg, *,
     ``table[b, pos // bs]``, offset ``pos % bs``), but the read dispatches
     on backend: TPU runs the flash-decoding Pallas kernel
     (``kernels.ops.paged_decode_attention`` — scalar-prefetched block-table
-    index maps, each grid step DMAs exactly one block), CPU runs its
+    index maps, each grid step DMAs a group of the blocks below the
+    sequence's length), CPU runs its
     pure-jnp oracle ``kernels.ref.paged_decode_attention_ref``.  ``backend``
     "kernel" / "ref" force a side (tests); "auto" picks by device.
     ``cfg.sliding_window`` configs run the kernel's windowed variant

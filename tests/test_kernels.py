@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
+from repro.kernels.decode_attention import _group_blocks
 
 
 def _rand(key, shape, dtype):
@@ -149,21 +150,44 @@ def test_tree_verify_attention_rectangular_levels(level):
 
 
 # ------------------------------------------------------------ paged decode
-@pytest.mark.parametrize("B,Kv,G,bs,MB,hd", [(1, 1, 1, 16, 4, 64),
-                                             (3, 2, 4, 16, 8, 64),
-                                             (2, 4, 2, 32, 4, 128)])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_paged_decode_attention_sweep(B, Kv, G, bs, MB, hd, dtype):
-    """Paged kernel (block-table scalar-prefetch gather) vs the pure-jnp
-    gather-then-dense oracle, with shuffled per-sequence block tables."""
+# (B, Kv, G, bs, MB, hd) at smollm-135m's and granite-8b's widths, whose
+# tables walk in several groups of blocks
+SMOLLM, GRANITE = (3, 3, 3, 32, 20, 64), (3, 8, 4, 32, 20, 128)
+PAGED_SHAPES = [(1, 1, 1, 16, 4, 64), (3, 2, 4, 16, 8, 64),
+                (2, 4, 2, 32, 4, 128), SMOLLM, GRANITE]
+_ID = "-".join
+
+
+def _paged_inputs(B, Kv, G, bs, MB, hd, dtype):
+    """q, pools, a shuffled block table (pool block 0 unused) and random
+    lengths in [1, MB*bs]."""
     NB = B * MB + 1
     q = _rand(0, (B, Kv, G, hd), dtype)
     k_pool = _rand(1, (NB, bs, Kv, hd), dtype)
     v_pool = _rand(2, (NB, bs, Kv, hd), dtype)
     rng = np.random.default_rng(0)
-    table = jnp.asarray(
-        rng.permutation(np.arange(1, NB))[:B * MB].reshape(B, MB), jnp.int32)
-    length = jnp.asarray(rng.integers(1, MB * bs + 1, B), jnp.int32)
+    table = rng.permutation(np.arange(1, NB))[:B * MB].reshape(B, MB)
+    return q, k_pool, v_pool, table, rng.integers(1, MB * bs + 1, B)
+
+
+@pytest.mark.parametrize("B,Kv,G,bs,MB,hd,lengths", [
+    *[pytest.param(*sh, "random", id=_ID(map(str, sh)))
+      for sh in PAGED_SHAPES],
+    *[pytest.param(*sh, "edges", id=_ID(["edges", *map(str, sh)]))
+      for sh in PAGED_SHAPES]])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_paged_decode_attention_sweep(B, Kv, G, bs, MB, hd, lengths, dtype):
+    """Paged kernel (block-table scalar-prefetch gather) vs the pure-jnp
+    gather-then-dense oracle, with shuffled per-sequence block tables.
+    ``edges`` lengths: one key; exactly one whole group of blocks; past
+    the table, as a masked slot's runaway ``pos`` reads."""
+    q, k_pool, v_pool, table, length = _paged_inputs(B, Kv, G, bs, MB, hd,
+                                                     dtype)
+    if lengths == "edges":
+        P = _group_blocks(bs, Kv, hd, dtype, MB)
+        length = np.resize([1, bs * P, MB * bs + 37], B)
+    table, length = jnp.asarray(table, jnp.int32), jnp.asarray(length,
+                                                               jnp.int32)
     o = ops.paged_decode_attention(q, k_pool, v_pool, table, length)
     o_ref = ref.paged_decode_attention_ref(q, k_pool, v_pool, table, length)
     tol = 1e-5 if dtype == jnp.float32 else 2e-2
@@ -171,21 +195,46 @@ def test_paged_decode_attention_sweep(B, Kv, G, bs, MB, hd, dtype):
                                np.asarray(o_ref, np.float32), atol=tol, rtol=tol)
 
 
-@pytest.mark.parametrize("window", [4, 8, 11, 16, 48])
-def test_paged_decode_attention_windowed(window):
-    """Windowed paged kernel (trailing-window blocks only, scalar-
-    prefetched start block) vs the windowed oracle — unaligned windows,
-    lengths below the window (early-position clamp), and windows past the
-    whole table included."""
-    B, Kv, G, bs, MB, hd = 4, 2, 4, 8, 6, 64
-    NB = B * MB + 1
-    q = _rand(0, (B, Kv, G, hd), jnp.float32)
-    k_pool = _rand(1, (NB, bs, Kv, hd), jnp.float32)
-    v_pool = _rand(2, (NB, bs, Kv, hd), jnp.float32)
-    rng = np.random.default_rng(0)
-    table = jnp.asarray(
-        rng.permutation(np.arange(1, NB))[:B * MB].reshape(B, MB), jnp.int32)
-    length = jnp.asarray([3, 17, 30, MB * bs], jnp.int32)
+@pytest.mark.parametrize("B,Kv,G,bs,MB,hd", [SMOLLM, GRANITE])
+def test_paged_decode_never_reads_past_length(B, Kv, G, bs, MB, hd):
+    """Every pool block but those below each slot's length holds NaN: the
+    kernel's output stays finite and equals the oracle on clean pools.  A
+    block read and masked would still poison ``p @ v`` (0 x NaN)."""
+    B = 4
+    q, k_pool, v_pool, table, _ = _paged_inputs(B, Kv, G, bs, MB, hd,
+                                                jnp.bfloat16)
+    P = _group_blocks(bs, Kv, hd, jnp.bfloat16, MB)
+    length = np.asarray([1, bs * P, bs * (P + 1) + 5, MB * bs + 37])
+    live = np.concatenate([table[b, :-(-min(n, MB * bs) // bs)]
+                           for b, n in enumerate(length)])
+    dead = np.setdiff1d(np.arange(k_pool.shape[0]), live)
+    table, length = jnp.asarray(table, jnp.int32), jnp.asarray(length,
+                                                               jnp.int32)
+    o = ops.paged_decode_attention(q, k_pool.at[dead].set(jnp.nan),
+                                   v_pool.at[dead].set(jnp.nan), table,
+                                   length)
+    o_ref = ref.paged_decode_attention_ref(q, k_pool, v_pool, table, length)
+    assert np.isfinite(np.asarray(o, np.float32)).all()
+    np.testing.assert_allclose(np.asarray(o, np.float32),
+                               np.asarray(o_ref, np.float32),
+                               atol=2e-2, rtol=2e-2)
+
+
+@pytest.mark.parametrize("shape,window", [
+    *[pytest.param((4, 2, 4, 8, 6, 64), w, id=str(w))
+      for w in (4, 8, 11, 16, 48)],
+    *[pytest.param((4, *SMOLLM[1:]), w, id=f"smollm-{w}") for w in (40, 600)]])
+def test_paged_decode_attention_windowed(shape, window):
+    """Windowed paged kernel (trailing-window blocks only, from the
+    scalar-prefetched start block) vs the windowed oracle — unaligned
+    windows, lengths below the window (early-position clamp), windows
+    past the whole table, and (smollm-135m widths) windows that span
+    groups of blocks."""
+    B, Kv, G, bs, MB, hd = shape
+    q, k_pool, v_pool, table, _ = _paged_inputs(B, Kv, G, bs, MB, hd,
+                                                jnp.float32)
+    table = jnp.asarray(table, jnp.int32)
+    length = jnp.asarray([3, 17 * bs // 8, 30 * bs // 8, MB * bs], jnp.int32)
     o = ops.paged_decode_attention(q, k_pool, v_pool, table, length,
                                    window=window)
     o_ref = ref.paged_decode_attention_ref(q, k_pool, v_pool, table, length,

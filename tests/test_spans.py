@@ -149,6 +149,70 @@ def test_tick_counters_add_up(edge_run):
         sum(-(-(n - 1) // TICK) for n in chunked)
 
 
+def _edge_states(eng, monkeypatch):
+    """Every decode state the engine's edge lane builds from now on."""
+    made = []
+    real = eng.edge.make_state
+
+    def make_state(*a, **kw):
+        made.append(real(*a, **kw))
+        return made[-1]
+    monkeypatch.setattr(eng.edge, "make_state", make_state)
+    return made
+
+
+def test_attn_block_counters_nest(edge_run):
+    """live <= visited <= table on every decoding tick; each emitting
+    slot-step walks at least one block."""
+    eng, _, _, log = edge_run
+    ticks = [(t, kids) for t, kids in _ticks(log)
+             if any(k.name == "edge.dispatch" for k in kids)]
+    assert ticks
+    for tick, kids in ticks:
+        c = tick.counts
+        n = next(k for k in kids if k.name == "edge.dispatch").counts[
+            "n_steps"]
+        assert c["attn_blocks_table"] % (n * B) == 0
+        assert c["slot_steps_active"] <= c["attn_blocks_live"] \
+            <= c["attn_blocks_visited"] <= c["attn_blocks_table"]
+    assert any(t.counts["attn_blocks_visited"] < t.counts["attn_blocks_table"]
+               for t, _ in ticks)
+
+
+@pytest.mark.parametrize("spans", [False, True], ids=["spans_off",
+                                                      "spans_on"])
+def test_pos_mirror_matches_device(pair, prompts, monkeypatch, spans):
+    """After a drain the host mirror of ``pos`` equals the device's (read
+    here, once; the engine never pulls it).  Spans off, no walk is
+    counted at all."""
+    from repro.core.seq_state import PagedKV
+    calls = []
+    real = PagedKV.attn_blocks
+    monkeypatch.setattr(PagedKV, "attn_blocks",
+                        lambda self, *a: calls.append(a) or real(self, *a))
+    eng = _engine(pair, ThresholdPolicy(1.1),
+                  spans=SpanLog() if spans else None)
+    made = _edge_states(eng, monkeypatch)
+    _serve(eng, pair, prompts)
+    state, = made
+    assert isinstance(state, PagedKV)
+    np.testing.assert_array_equal(state.pos_h,
+                                  np.asarray(state.caches["pos"]))
+    if spans:
+        assert len(calls) == sum(
+            "attn_blocks_table" in s.counts for s in eng.spans.spans) > 0
+    else:
+        assert calls == []
+    # a 2-step tick: slot 0 attends bs then bs + 1 keys (one block, then
+    # two), slot 1 runs past its table (MB each step); slot 1 masked in
+    # the first step
+    bs, MB = state.block_size, state.max_blocks
+    state.pos_h[:] = [bs + 1, MB * bs + 7]
+    assert real(state, 2, np.array([[True, False], [True, True]])) == {
+        "attn_blocks_table": 4 * MB, "attn_blocks_visited": 3 + 2 * MB,
+        "attn_blocks_live": 3 + MB}
+
+
 @pytest.mark.parametrize("policy", ["edge", "escalated"])
 def test_request_stamps_in_lifecycle_order(pair, prompts, edge_run, policy):
     if policy == "edge":
