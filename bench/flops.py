@@ -4,16 +4,21 @@ These count the work of the configured algorithm, not what a compiled
 program happens to execute: no pow2 overshoot, no padding, no masked
 slots or blocks.  A later change to the program cannot move them.
 
-``cfg`` is a model entry of a configuration file (``d_model``,
-``num_heads``, ``num_kv_heads``, ``head_dim``, ``d_ff``, ``vocab_size``,
-``num_layers``).  Bytes assume bf16 (2 bytes) activations, weights and
-KV cache, as the configurations state.
+``cfg`` is a model entry of a configuration file.  What one token costs
+through a whole model is its family's count (``token_flops`` and
+``attn_flops`` in ``bench/families/<family>.py``); here are the counts
+every family shares: contexts, one layer's paged decode attention
+(``num_heads``, ``num_kv_heads``, ``head_dim``), the peaks and the
+roofline.  Bytes assume bf16 (2 bytes) activations, weights and KV
+cache, as the configurations state.
 """
 from __future__ import annotations
 
 import json
 from pathlib import Path
 from typing import Iterable
+
+from bench import families
 
 BYTES = 2
 PEAKS_FILE = Path(__file__).resolve().parent / "peaks.json"
@@ -30,28 +35,6 @@ def peaks(device_kind: str) -> dict:
     return table[device_kind]
 
 
-def layer_matmul_params(cfg: dict) -> int:
-    """Weights one token multiplies through in one layer."""
-    d, hd = cfg["d_model"], cfg["head_dim"]
-    H, Kv = cfg["num_heads"], cfg["num_kv_heads"]
-    return d * H * hd + 2 * d * Kv * hd + H * hd * d + 3 * d * cfg["d_ff"]
-
-
-def token_flops(cfg: dict) -> int:
-    """Matmul FLOPs of one token through the model, output head included
-    (2 per multiply-add); attention over the context is apart."""
-    return 2 * (cfg["num_layers"] * layer_matmul_params(cfg)
-                + cfg["vocab_size"] * cfg["d_model"])
-
-
-def attn_flops(cfg: dict, ctx_sum: float) -> float:
-    """Attention FLOPs of all layers for query tokens whose attended
-    context lengths sum to ``ctx_sum``: QK^T and PV, 2 FLOPs each per
-    (query head, key, head dim)."""
-    return 4.0 * cfg["num_layers"] * cfg["num_heads"] * cfg["head_dim"] \
-        * ctx_sum
-
-
 def prefill_ctx(n: int) -> float:
     """Summed causal context of a prefill of ``n`` tokens."""
     return n * (n + 1) / 2.0
@@ -64,7 +47,10 @@ def decode_ctx(start: float, n: float) -> float:
 
 
 def forward_flops(cfg: dict, tokens: float, ctx_sum: float) -> float:
-    return tokens * token_flops(cfg) + attn_flops(cfg, ctx_sum)
+    """FLOPs of ``tokens`` tokens through the model whose attended context
+    lengths sum to ``ctx_sum``, by the model's family's counts."""
+    fam = families.of(cfg)
+    return tokens * fam.token_flops(cfg) + fam.attn_flops(cfg, ctx_sum)
 
 
 def paged_decode_attn(cfg: dict, lengths: Iterable[float]) -> dict:

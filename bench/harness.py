@@ -1,15 +1,19 @@
 """The benchmark harness: one cell, one seed, one measured window.
 
 Everything cell-specific is found by name: the cell in ``BENCHMARK.json``,
-its configuration in ``bench/configs/<config>.json``, its traffic mix in
+its configuration in ``bench/configs/<config>.json``, each model's
+weights, reference and work counts in ``bench/families/<family>.py``
+(``bench/families/__init__.py`` gives the contract), its traffic mix in
 ``bench/traffic/<traffic>.json``, and each metric's reader in
 ``bench/metrics/<metric>.py`` (a ``read(run) -> float | None``).
 
 A run: check the chips (before the program is imported), turn on the
 persistent compilation cache, make both models' weights on the device from
 the seed, build one ``BatchedEngine`` and drive it through one warm-up
-drain of the mix's shapes (set-up ends here), then the measured window,
-then the comparison with the float32 reference that decides ``correct``.
+drain of the mix's shapes and its eager table updates at every count
+(set-up ends here), then the measured window (a traced run hands the
+engine a ``SpanLog`` for it), then the comparison with the float32
+reference that decides ``correct``.
 """
 from __future__ import annotations
 
@@ -38,6 +42,8 @@ TRACE_DIR = ROOT / ".bench_trace"
 # longest length: a short steady slice keeps the trace small to read
 TRACE_AT = 0.3
 TRACE_MAX_S = 6.0
+# tokens per block of the engine's paged KV cache
+KV_BLOCK = 32
 # the correctness sample: served tokens and requests per compared model
 SAMPLE_TOKENS = 384
 SAMPLE_REQUESTS = 8
@@ -214,30 +220,66 @@ class SubWindowTrace:
 class Run:
     """What a metric reader reads: the cell, its configuration and mix,
     the request records of the window (times in ms from the window's
-    start), set-up seconds and, in a traced run, the reduced trace and
-    its sub-window."""
+    start), set-up seconds and, in a traced run, the reduced trace, its
+    sub-window and the engine's spans of the window (``spans``, a list of
+    ``core/spans.py`` ``Span``; None in an untraced run)."""
 
     def __init__(self, **kw):
         self.__dict__.update(kw)
 
 
 def _build(cfg: dict, mix: dict, seed: int):
+    """Both models' family files (looked up first: a family with no file
+    fails before the program is built), the program's models, both
+    models' weights from the seed, and the engine.  The same ``family``
+    names the program's model family (``ModelConfig.family``)."""
     from repro.core.scheduler import BatchedEngine
     from repro.core.traffic import WallClock
     from repro.configs.base import ModelConfig
     from repro.models import Model
-    from bench import weights as W
+    from bench import families
     from bench.policy import MarkedEscalation
+    fe, fc = families.of(cfg["edge"]), families.of(cfg["cloud"])
     edge, cloud = Model(ModelConfig(**cfg["edge"])), \
         Model(ModelConfig(**cfg["cloud"]))
-    ep = W.make_on_device(seed, 0, cfg["edge"])
-    cp = W.make_on_device(seed, 1, cfg["cloud"])
+    ep = fe.make_on_device(seed, 0, cfg["edge"])
+    cp = fc.make_on_device(seed, 1, cfg["cloud"])
     policy = MarkedEscalation(mix["escalate_action"])
     engine = BatchedEngine(edge, cloud, batch_size=mix["batch"],
                            use_cache=False, clock=WallClock(),
-                           kv_layout="paged", kv_block_size=32,
+                           kv_layout="paged", kv_block_size=KV_BLOCK,
                            policy=policy, spec_mode=mix["spec_mode"])
     return engine, policy, ep, cp
+
+
+def warm_table_updates(engine) -> None:
+    """Run once, at every index count the window can reach, the eager
+    block-table updates that the paged engine makes between its programs:
+    ``PagedKV.flush`` (the rows and positions of the slots admitted or
+    retired in a tick, up to the batch) and ``PagedKV.prepare_tick`` (an
+    entry per block that a slot grows into in a tick, up to the batch
+    times the blocks a tick's steps can span).  JAX compiles such an
+    update once per index count, so a count first met in the window
+    would compile there.  On arrays of the table's shape, not the
+    engine's: its state is not touched."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    batch = engine.batch_size
+    width = -(-engine._slot_len // KV_BLOCK)
+    table = jnp.zeros((batch, width), jnp.int32)
+    pos = jnp.zeros((batch,), jnp.int32)
+    out = []
+    for k in range(1, batch + 1):
+        ii = jnp.asarray(list(range(k)), jnp.int32)
+        out.append(table.at[ii].set(jnp.asarray(np.zeros((k, width),
+                                                          np.int32))))
+        out.append(pos.at[ii].set(jnp.asarray([0] * k, jnp.int32)))
+    for k in range(1, batch * -(-engine.tick_tokens // KV_BLOCK) + 1):
+        out.append(table.at[jnp.asarray([0] * k, jnp.int32),
+                            jnp.asarray(list(range(k)), jnp.int32)].set(
+            jnp.asarray([0] * k, jnp.int32)))
+    jax.block_until_ready(out)
 
 
 def _submit(engine, policy, reqs, base_ms: Optional[float]):
@@ -254,9 +296,11 @@ def _submit(engine, policy, reqs, base_ms: Optional[float]):
 
 
 def _records(engine, results, reqs, rids, base_ms: float) -> List[dict]:
-    """One record per request, times in ms from the window's start.  An
-    escalated request's first token is the wave's return (``retire``):
-    only then does the host hold any of its tokens."""
+    """One record per request, times in ms from the window's start (the
+    engine's stamps: admission, end of prefill, first token ready on the
+    host, retirement).  An escalated request's first token is the wave's
+    return (``retire``): only then does the host hold any of its
+    tokens."""
     out = []
     for r, rid in zip(reqs, rids):
         ev = engine.events.get(rid, {})
@@ -264,9 +308,12 @@ def _records(engine, results, reqs, rids, base_ms: float) -> List[dict]:
         rec = {"rid": rid, "prompt": r["prompt"], "max_new": r["max_new"],
                "escalated": r["escalate"], "due_ms": r["at_ms"],
                "admit_ms": ev.get("admit_ms"), "retire_ms": ev.get("retire_ms"),
+               "prefill_done_ms": ev.get("prefill_done_ms"),
+               "first_token_ready_ms": ev.get("first_token_ready_ms"),
                "tokens": None if tr is None or tr.tokens is None
                else list(tr.tokens)}
-        for k in ("admit_ms", "retire_ms"):
+        for k in ("admit_ms", "retire_ms", "prefill_done_ms",
+                  "first_token_ready_ms"):
             if rec[k] is not None:
                 rec[k] -= base_ms
         if rec["retire_ms"] is not None:
@@ -319,12 +366,14 @@ class Session:
         self.devices = jax.devices()[:1]
 
     def warm(self) -> float:
-        """The warm-up drain (``traffic.warmup_requests``) on this engine.
-        Returns the set-up seconds (process start to its end)."""
+        """The warm-up drain (``traffic.warmup_requests``) on this engine,
+        then its eager block-table updates at every count
+        (``warm_table_updates``).  Returns the set-up seconds (process start to its end)."""
         warm = T.warmup_requests(self.mix, self.cfg["edge"]["vocab_size"],
                                  self.engine.tick_tokens)
         _submit(self.engine, self.policy, warm, None)
         self.engine.run(*self.params)
+        warm_table_updates(self.engine)
         self.setup_s = time.perf_counter() - self.t_start
         c = self.watch.snapshot()
         print(f"setup: setup_s={self.setup_s:.3f} compiles={c[0]} "
@@ -335,24 +384,32 @@ class Session:
     def reseed(self, seed: int) -> None:
         """Replace both models' weights by those of ``seed`` (same shapes,
         so no compile)."""
-        from bench import weights as W
+        from bench import families
         self.params = None
         gc.collect()
-        self.params = tuple(W.make_on_device(seed, i, self.cfg[m])
-                            for i, m in enumerate(("edge", "cloud")))
+        self.params = tuple(
+            families.of(self.cfg[m]).make_on_device(seed, i, self.cfg[m])
+            for i, m in enumerate(("edge", "cloud")))
 
     def measure(self, seed: int, seconds: float, trace: bool,
                 control: bool = False) -> dict:
         """One measured window under ``seed``'s traffic, its metrics, then
         (the program's weights freed) the comparison that decides
-        ``correct``.  Returns the result dict (the last stdout line)."""
+        ``correct``.  A traced window records the engine's spans; the
+        warm-up and untraced windows record none.  Returns the result
+        dict (the last stdout line)."""
         engine, policy = self.engine, self.policy
         policy.drafts.clear()
         c0 = self.watch.snapshot()
         tdir = str(TRACE_DIR / f"{self.name}-{seed}") if trace else None
+        log = None
+        if trace:
+            from repro.core.spans import SpanLog
+            log = engine.spans = SpanLog()
         with CompileNames() as names:
             recs, tracer = _window(engine, policy, self.params, self.mix,
                                    self.cfg, seed, seconds, tdir)
+        engine.spans = None
         c1 = self.watch.snapshot()
         print(f"window: compiles={c1[0] - c0[0]} "
               f"compile_s={c1[1] - c0[1]:.3f} cache_loads={c1[2] - c0[2]} "
@@ -362,7 +419,8 @@ class Session:
                   seconds=seconds, records=recs, setup_s=self.setup_s,
                   chips=len(self.devices),
                   device_kind=self.info["kind"], trace=None,
-                  trace_window=None)
+                  trace_window=None,
+                  spans=None if log is None else log.spans)
         out = {}
         if trace:
             from bench import trace as TR

@@ -33,6 +33,21 @@ def _cpu(n: int) -> dict:
             "count": len(d)}
 
 
+def session(kind: str, seed: int, cfg: dict = CFG, spec: dict = None):
+    """A harness ``Session`` on the tiny configuration (or ``cfg``), set up
+    and warmed, with the look for a chip left out."""
+    from unittest import mock
+
+    from bench import harness as H
+    name, mix = CELLS[kind]
+    cell = {"name": name, "chips": 1}
+    with mock.patch.object(H, "require_chips", _cpu):
+        s = H.Session(name, cell, copy.deepcopy(cfg), copy.deepcopy(mix),
+                      spec or H.load_spec(), seed=seed)
+    s.warm()
+    return s
+
+
 def run(kind: str, seed: int, control: bool = False, seconds: float = 3.0):
     from unittest import mock
 

@@ -1,11 +1,13 @@
 """Shape-derived FLOP and byte counts against hand counts at smollm-135m
-widths, and the peaks table."""
+widths (the dense family's counts and the shared ones), and the peaks
+table."""
 import json
 from pathlib import Path
 
 import pytest
 
 from bench import flops as F
+from bench.families import dense as D
 
 CFG = json.loads((Path(__file__).resolve().parents[1] / "configs" /
                   "smollm135m-granite8b-L16.json").read_text())
@@ -21,9 +23,9 @@ def test_smollm_widths():
 def test_layer_and_token_flops_by_hand():
     # q 576*576 + k, v 2*576*192 + o 576*576 + gate/up/down 3*576*1536
     per_layer = 331776 + 221184 + 331776 + 2654208
-    assert F.layer_matmul_params(EDGE) == per_layer == 3538944
+    assert D.layer_matmul_params(EDGE) == per_layer == 3538944
     # 30 layers + the tied head 49152*576, 2 FLOPs per multiply-add
-    assert F.token_flops(EDGE) == 2 * (30 * 3538944 + 28311552) \
+    assert D.token_flops(EDGE) == 2 * (30 * 3538944 + 28311552) \
         == 268959744
 
 
@@ -31,15 +33,15 @@ def test_attention_counts_by_hand():
     assert F.prefill_ctx(4) == 10            # 1 + 2 + 3 + 4 keys
     assert F.decode_ctx(3, 2) == 9           # steps attend 4, then 5 keys
     # 4 * 9 heads * 64 * ctx * 30 layers
-    assert F.attn_flops(EDGE, 10) == 4 * 9 * 64 * 10 * 30
+    assert D.attn_flops(EDGE, 10) == 4 * 9 * 64 * 10 * 30
     assert F.forward_flops(EDGE, 4, 10) == 4 * 268959744 + 4 * 9 * 64 * 300
 
 
 def test_prefill_decode_verify_granite():
     # granite-8b layer: q, o 4096^2 each; k, v 4096*1024 each; mlp 3*4096*14336
     per = 2 * 4096 * 4096 + 2 * 4096 * 1024 + 3 * 4096 * 14336
-    assert F.layer_matmul_params(CLOUD) == per
-    assert F.token_flops(CLOUD) == 2 * (16 * per + 49152 * 4096)
+    assert D.layer_matmul_params(CLOUD) == per
+    assert D.token_flops(CLOUD) == 2 * (16 * per + 49152 * 4096)
     # a verify of 5 positions after 100 committed: contexts 101..105
     assert F.decode_ctx(100, 5) == 101 + 102 + 103 + 104 + 105
 

@@ -1,11 +1,13 @@
 """The float32 reference against the program's own forward pass at a
-small size on the CPU, on the benchmark's seeded weights."""
+small size on the CPU, on the benchmark's seeded weights (the dense
+family's)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from bench import reference as R
 from bench import weights as W
+from bench.families import dense as D
 
 CFG = {"name": "tiny", "family": "dense", "num_layers": 3, "d_model": 64,
        "num_heads": 4, "num_kv_heads": 2, "head_dim": 16, "d_ff": 96,
@@ -17,9 +19,9 @@ SEED = 2**32 + 3
 
 def test_stacked_leaves_equal_per_layer_draws():
     key = W.model_key(SEED, 1)
-    full = W.program_params(key, CFG)
+    full = D.program_params(key, CFG)
     for layer in range(CFG["num_layers"]):
-        one = W.layer_params(key, layer, CFG)
+        one = D.layer_params(key, layer, CFG)
         np.testing.assert_array_equal(np.asarray(full["blocks"]["attn"]["wk"][layer]),
                                       np.asarray(one["wk"]))
         np.testing.assert_array_equal(np.asarray(full["blocks"]["mlp"]["w_down"][layer]),
@@ -34,11 +36,11 @@ def test_reference_matches_program_prefill_logits():
     from repro.models import transformer
     cfg = ModelConfig(**CFG)
     params = jax.tree.map(lambda x: x.astype(jnp.float32),
-                          W.program_params(W.model_key(SEED, 0), CFG))
+                          D.program_params(W.model_key(SEED, 0), CFG))
     rng = np.random.default_rng(0)
     seqs = [rng.integers(0, 300, n).astype(np.int32) for n in (37, 20)]
     starts = [30, 5]
-    ref = R.logits_at(CFG, SEED, 0, seqs, starts)
+    ref = D.logits_at(CFG, SEED, 0, seqs, starts)
     for s, st, lg in zip(seqs, starts, ref):
         with jax.default_matmul_precision("highest"):
             prog, _ = transformer.forward(params, jnp.asarray(s[None]), cfg)

@@ -27,22 +27,28 @@ def _trace(ops, host):
 
 
 # ---------------------------------------------------------------- host gap
-# two ticks of 100 ms, each with its readback; the device runs ops around
-# and across them.  Idle inside tick 1 outside its readback [60, 90]:
-# [0, 10] + [30, 50] + [90, 95] = 35 ms; inside tick 2 outside [250, 290]:
-# [200, 240] + [290, 300] = 50 ms.  The device gap [120, 200] lies between
-# ticks, and [70, 90] inside a readback: neither counts.
+# two decoding ticks of 100 ms, each with its dispatch and readback; the
+# device runs ops around and across them.  Idle inside tick 1 outside its
+# readback [60, 90]: [0, 10] + [30, 50] + [90, 95] = 35 ms; inside tick 2
+# outside [250, 290]: [200, 240] + [290, 300] = 50 ms.  The device gap
+# [120, 200] lies between ticks, and [70, 90] inside a readback: neither
+# counts.  Tick 3, [400, 450], dispatches nothing (every slot mid-prefill):
+# its 50 idle ms are not a decoding tick's, so neither it nor its gap
+# counts.
 OPS = [["fusion.1", 10 * MS, 20 * MS], ["fusion.2", 50 * MS, 20 * MS],
        ["copy.3", 95 * MS, 25 * MS], ["fusion.1", 240 * MS, 20 * MS]]
-TICKS = [["engine.tick", 0.0, 100 * MS], ["engine.tick", 200 * MS, 100 * MS]]
+TICKS = [["engine.tick", 0.0, 100 * MS], ["engine.tick", 200 * MS, 100 * MS],
+         ["engine.tick", 400 * MS, 50 * MS]]
 READBACKS = [["edge.readback", 60 * MS, 30 * MS],
              ["edge.readback", 250 * MS, 40 * MS]]
+DISPATCHES = [["edge.dispatch", 55 * MS, 5 * MS],
+              ["edge.dispatch", 245 * MS, 5 * MS]]
 
 
 @pytest.mark.parametrize("lines", [
-    {"python3": TICKS + READBACKS},                       # one line
-    {"python3": [["$scheduler.py:420 _run_impl", 0.0, 300 * MS]] + TICKS,
-     "main/291": READBACKS},                              # split over lines
+    {"python3": TICKS + DISPATCHES + READBACKS},          # one line
+    {"python3": [["$scheduler.py:420 _run_impl", 0.0, 450 * MS]] + TICKS,
+     "main/291": DISPATCHES + READBACKS},                 # split over lines
 ])
 def test_host_gap_by_hand(lines):
     got = H.reader("host_gap_ms")(_run(trace=_trace(OPS, lines)))
@@ -52,8 +58,11 @@ def test_host_gap_by_hand(lines):
 def test_host_gap_busy_device_and_no_spans():
     busy = [["fusion.9", -1 * MS, 400 * MS]]
     read = H.reader("host_gap_ms")
-    assert read(_run(trace=_trace(busy, {"python3": TICKS + READBACKS}))) \
-        == 0.0
+    assert read(_run(trace=_trace(
+        busy, {"python3": TICKS + DISPATCHES + READBACKS}))) == 0.0
+    # ticks that dispatch nothing: no decoding tick to read
+    assert read(_run(trace=_trace(OPS, {"python3": TICKS + READBACKS}))) \
+        is None
     assert read(_run(trace=_trace(OPS, {"python3": [["$frame", 0.0, MS]]}))) \
         is None
     assert read(_run()) is None

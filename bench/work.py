@@ -13,6 +13,7 @@ from __future__ import annotations
 
 from typing import Iterator, Tuple
 
+from bench import families
 from bench import flops as F
 from bench.latency import completed
 
@@ -62,9 +63,10 @@ def mfu(run):
 
 
 def edge_decode_attention(run, t0: float, t1: float) -> dict:
-    """FLOPs and bytes the edge's single-token decode attention needs, all
-    layers, for the edge-served requests' decode steps inside [t0, t1], at
-    each sequence's live length."""
+    """FLOPs and bytes the edge's single-token decode attention needs, in
+    every layer that runs it (its family's ``paged_layers``), for the
+    edge-served requests' decode steps inside [t0, t1], at each sequence's
+    live length."""
     cfg = run.cfg["edge"]
     fl = by = 0.0
     for rec in edge_served(run):
@@ -79,5 +81,5 @@ def edge_decode_attention(run, t0: float, t1: float) -> dict:
         w = F.paged_decode_attn(cfg, [mean_len])
         fl += steps * w["flops"]
         by += steps * w["bytes"]
-    L = cfg["num_layers"]
+    L = families.of(cfg).paged_layers(cfg)
     return {"flops": L * fl, "bytes": L * by}
