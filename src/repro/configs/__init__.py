@@ -23,6 +23,7 @@ _MODULES = {
     "nemotron-4-15b": "nemotron_4_15b",
     "zamba2-2.7b": "zamba2_2_7b",
     "granite-8b": "granite_8b",
+    "granite-4.0-h-micro": "granite_4_0_h_micro",
 }
 
 

@@ -16,7 +16,7 @@ from typing import Tuple
 class ModelConfig:
     # identity
     name: str
-    family: str              # dense | moe | ssm | xlstm | hybrid | encdec | vlm
+    family: str              # dense | moe | ssm | xlstm | hybrid | granitehybrid | encdec | vlm
     source: str = ""                 # citation (arXiv id / hf model card)
 
     # transformer backbone
@@ -28,7 +28,9 @@ class ModelConfig:
     vocab_size: int = 0
     head_dim: int = 0                # 0 -> d_model // num_heads
     mlp_activation: str = "silu"     # silu | relu2 | gelu | geglu
-    use_rope: bool = True            # False -> learned positional embeddings
+    use_rope: bool = True            # False -> no rotary; attention sees no
+                                     # position (NoPE) unless the family adds
+                                     # learned ones (encdec does)
     max_position_embeddings: int = 1 << 20
     rope_theta: float = 10_000.0
     norm_eps: float = 1e-5
@@ -55,6 +57,20 @@ class ModelConfig:
     # hybrid (zamba2-style): one *shared* attention block applied periodically
     shared_attn_every: int = 0       # 0 -> no shared attention block
 
+    # per-layer kinds (granitehybrid): "mamba" | "attention" for each layer,
+    # each followed by the MLP; () -> every layer is the family's one block
+    layer_types: Tuple[str, ...] = ()
+
+    # granite multipliers (1.0 / 0.0 -> off): the embedding is scaled by
+    # ``embedding_multiplier``, attention scores by ``attention_multiplier``
+    # (0 -> 1/sqrt(head_dim)), each mixer and MLP output by
+    # ``residual_multiplier`` before its residual add, and the logits are
+    # divided by ``logits_scaling``
+    embedding_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+
     # encoder-decoder (whisper-style); encoder consumes precomputed frame
     # embeddings (conv/mel frontend is a stub per the assignment carve-out).
     encoder_layers: int = 0
@@ -75,10 +91,24 @@ class ModelConfig:
     def __post_init__(self):
         if self.head_dim == 0 and self.num_heads:
             object.__setattr__(self, "head_dim", self.d_model // self.num_heads)
+        # a JSON config gives a list; a tuple keeps the config hashable
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        if self.layer_types and len(self.layer_types) != self.num_layers:
+            raise ValueError(f"{len(self.layer_types)} layer_types for "
+                             f"{self.num_layers} layers")
+        bad = set(self.layer_types) - {"mamba", "attention"}
+        if bad:
+            raise ValueError(f"unknown layer_types {sorted(bad)}; "
+                             "known: mamba | attention")
+
+    def layers_of(self, kind: str) -> int:
+        """How many layers are of ``kind`` (``layer_types``)."""
+        return sum(k == kind for k in self.layer_types)
 
     @property
     def is_decoder_only(self) -> bool:
-        return self.family in ("dense", "moe", "ssm", "xlstm", "hybrid", "vlm")
+        return self.family in ("dense", "moe", "ssm", "xlstm", "hybrid",
+                               "granitehybrid", "vlm")
 
     @property
     def has_attention(self) -> bool:
@@ -118,6 +148,17 @@ class ModelConfig:
             attn = d * hd * (self.num_heads + 2 * self.num_kv_heads) + self.num_heads * hd * d
             shared = attn + 3 * d * self.d_ff   # counted once: weights shared
             return L * mamba + (shared if n_shared else 0) + emb
+        if self.family == "granitehybrid":
+            d_in = self.ssm_expand * d
+            H = d_in // self.ssm_head_dim
+            C = d_in + 2 * self.ssm_state          # conv channels (x, B, C)
+            mamba = (d * (2 * d_in + 2 * self.ssm_state + H) + d_in * d
+                     + self.conv_kernel * C + C + 3 * H + d_in)
+            attn = d * hd * (self.num_heads + 2 * self.num_kv_heads) \
+                + self.num_heads * hd * d
+            per = 3 * d * self.d_ff + 2 * d          # MLP and two norms
+            return (self.layers_of("mamba") * mamba
+                    + self.layers_of("attention") * attn + L * per + emb + d)
         if self.family == "encdec":
             attn = 4 * d * d
             mlp = 2 * d * self.d_ff
@@ -141,8 +182,9 @@ class ModelConfig:
         return dataclasses.replace(self, **kw)
 
     def reduced(self) -> "ModelConfig":
-        """Smoke-test variant: <=2 layers, d_model<=256, <=4 experts,
-        small vocab. Same family/block pattern so the code path is identical."""
+        """Smoke-test variant: <=2 layers (3 with per-layer kinds: attention
+        between two mamba layers), d_model<=256, <=4 experts, small vocab.
+        Same family/block pattern so the code path is identical."""
         kw = dict(
             num_layers=2,
             d_model=min(self.d_model, 256),
@@ -174,6 +216,9 @@ class ModelConfig:
             kw.update(ssm_state=16, ssm_chunk=8)
         if self.family in ("ssm", "xlstm"):
             kw["ssm_chunk"] = 8
+        if self.layer_types:
+            kw.update(num_layers=3,
+                      layer_types=("mamba", "attention", "mamba"))
         return self.replace(**kw)
 
 

@@ -22,7 +22,8 @@ tokens) but executes them slot-based and batched:
     zero — and jit-cached per bucket, not per distinct length); prompts
     longer than ``prefill_chunk`` entries (default: ``tick_tokens``)
     prefill ONE chunk per tick into a detached cache
-    (``Lane.start_prefill`` / ``advance_prefill``) interleaved with the
+    (``Lane.start_prefill`` / ``SequenceState.advance_prefill``)
+    interleaved with the
     batch's decode ticks, then land through ``SequenceState.finalize`` —
     a long prompt no longer stalls every in-flight slot behind a
     monolithic prefill.  Either way the finished cache reaches the slot
@@ -709,6 +710,7 @@ class BatchedEngine:
                     unc_h[bs] = 0.0
 
             if cloud_wave:
+                self._check_escalation("cloud")
                 # cloud-assigned lane: one grouped batched cloud generation
                 # for the wave (task assignment at admission).  First-token
                 # time is the generation's own first step, not the (later)
@@ -738,14 +740,23 @@ class BatchedEngine:
             for b in list(self._prefill_jobs):
                 job = self._prefill_jobs[b]
                 before = job["done"]
-                finished = self.edge.advance_prefill(edge_params, job)
+                finished = state.advance_prefill(b, edge_params, job)
                 clock.on_prefill(job["done"] - before)
                 if sp is not None:
                     sp.count(tokens=job["done"] - before)
                 if finished:
-                    state.finalize(b, job["cache"])
-                    del self._prefill_jobs[b]
                     r = slots[b].req
+                    # a layout with recurrent state rows commits the slot's
+                    # row, prefilled in place, to the tick here, beside its
+                    # K/V blocks
+                    row_bytes = state.state_row_bytes
+                    if sp is not None and row_bytes:
+                        sp.begin("state.commit", rid=r.rid, rows=1,
+                                 bytes=row_bytes)
+                    state.finalize(b, job["cache"])
+                    if sp is not None and row_bytes:
+                        sp.end()
+                    del self._prefill_jobs[b]
                     ev = self._events[r.rid]
                     ev["prefill_done_ms"] = clock.now()
                     ev["prefill_chunks"] = -(-job["done"]
@@ -796,7 +807,10 @@ class BatchedEngine:
                 sp.mark("kv.prepare_tick")
             state.prepare_tick(occupied, steps_h, n)
             if sp is not None:
-                sp.mark("edge.dispatch", n_steps=n)
+                # recurrent state rows read and written per step, if any
+                rows = state.state_rows
+                sp.mark("edge.dispatch", n_steps=n,
+                        **({"state_rows": rows} if rows else {}))
             rng, r = jax.random.split(rng)
             state.caches, tok, steps, unc, toks, actives = self.edge._chunk(
                 edge_params, state.caches, tok, steps, unc, r, stop,
@@ -894,6 +908,7 @@ class BatchedEngine:
                 # The escalation's own first step is the client-visible
                 # first token (the edge stream it replaces was discarded)
                 for a, grp in groups.items():
+                    self._check_escalation(a)
                     rng, r = jax.random.split(rng)
                     t_esc = clock.now()
                     for req, tr in self._esc_fns[a](
@@ -914,6 +929,26 @@ class BatchedEngine:
         self._kv_stats["preemptions"] = self._preempts
         self._kv_stats.update(state.stats())
         return results
+
+    def _check_escalation(self, action: str) -> None:
+        """Refuse, where a request would escalate, what the two models
+        cannot share: the cloud reads the edge's token ids, so their
+        vocabularies must be one; speculation rewinds both models' states
+        to the accepted prefix, so both must rewind."""
+        ev = self.edge_model.cfg.vocab_size
+        cv = self.cloud_model.cfg.vocab_size
+        if ev != cv:
+            raise ValueError(
+                f"cannot escalate ({action}): the edge's vocab_size {ev} and "
+                f"the cloud's vocab_size {cv} differ, so the models share no "
+                "token ids")
+        if action == "speculative" and not self.spec.rewindable:
+            raise ValueError(
+                "cannot escalate (speculative): the speculation lane cannot "
+                "rewind the state of family "
+                f"{self.edge_model.cfg.family!r} (edge) / "
+                f"{self.cloud_model.cfg.family!r} (cloud) on layout "
+                f"{self.kv_layout!r}")
 
     @hot_path
     def _pick_victim(self, state, slots, steps_h, wave) -> Optional[int]:

@@ -24,6 +24,9 @@ same slot/tick/escalation machinery:
       - ``RecurrentState`` — fixed-size recurrent state (ssm/xlstm/hybrid):
         dense stacked storage (there is no sequence axis to page), its own
         class so layout policy stays out of the scheduler.
+      - ``PagedState`` — ``PagedKV`` for the attention layers beside one
+        row of recurrent state per slot (granitehybrid's Mamba2 layers),
+        both in one cache pytree.
 
   * ``SpecOps`` — the traceable (jit-safe) per-model ops speculative
     decoding composes: ``step`` / ``extend`` for drafting and verification,
@@ -50,6 +53,7 @@ R2, asserted at runtime by the ``compile_stability`` bench arm).
 """
 from __future__ import annotations
 
+import functools
 import hashlib
 from collections import Counter
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -142,6 +146,10 @@ class SpecOps:
     def __init__(self, model, layout: str):
         self.model = model
         self.layout = layout
+        # speculation commits by a ``pos`` write (KV layouts) or by replay
+        # (the recurrent layout); a paged cache with recurrent state rows
+        # can do neither
+        self.rewindable = layout == "recurrent" or model.rewindable_cache
         if layout == "paged":
             self._step = lambda p, t, c: model.paged_decode_step(p, t[:, :, 0], c)
             self._extend = model.paged_extend_step
@@ -287,6 +295,12 @@ class SequenceState:
         ``flush`` batches the device writes as for ``admit``)."""
         raise NotImplementedError
 
+    def advance_prefill(self, b: int, params, job: dict) -> bool:
+        """Advance slot ``b``'s chunked prefill job (``Lane.start_prefill``)
+        one chunk into its detached cache; True when every entry is in.
+        Layouts with rows of recurrent state advance the slot's own row."""
+        return self.lane.advance_prefill(params, job)
+
     def detached_len(self, entry_count: int) -> int:
         """Padded length of a detached chunked-prefill cache for a prompt
         with ``entry_count`` entries (layout-dependent: dense slots pad to
@@ -317,6 +331,18 @@ class SequenceState:
         """Counts of the paged decode kernel's walk over the tick that
         ``advance(n)`` just mirrored; none on layouts without a table."""
         return {}
+
+    @property
+    def state_rows(self) -> int:
+        """Slot rows of recurrent state that each step of the tick program
+        reads and writes (0 on layouts that page everything)."""
+        return 0
+
+    @property
+    def state_row_bytes(self) -> int:
+        """Bytes of one slot's row of recurrent state, which a finished
+        prefill commits to the tick (0 on layouts that page everything)."""
+        return 0
 
     def retire(self, b: int):
         """Release slot ``b``'s capacity."""
@@ -725,14 +751,7 @@ class PagedKV(SequenceState):
         None when every block was shared)."""
         E = entries.size
         if blocks:
-            nb = self.pool.blocks_for(E)
-            kb, vb = prompt_cache_to_blocks(
-                {"k": c1["k"][:, :, :nb * self.block_size],
-                 "v": c1["v"][:, :, :nb * self.block_size]},
-                self.block_size)
-            self.caches["k"], self.caches["v"] = write_pool_blocks(
-                self.caches["k"], self.caches["v"],
-                jnp.asarray(blocks, jnp.int32), kb[:, ns:], vb[:, ns:])
+            self._write_blocks(b, E, blocks, ns, c1)
         mine = self.pool.owned(b)
         # pad = trap block (the slot's shard's trap on sharded pools)
         row = np.full((self.max_blocks,), self.pool.trap(b), np.int32)
@@ -742,6 +761,19 @@ class PagedKV(SequenceState):
         self._entries[b] = entries
         self._stale.discard(b)
         self._register(entries, mine, self._shard_of(b))
+
+    def _write_blocks(self, b: int, E: int, blocks: List[int], ns: int,
+                      c1) -> None:
+        """Write a prefilled prompt's K/V (``E`` entries) into the blocks
+        allocated for it, those after its ``ns`` shared ones."""
+        nb = self.pool.blocks_for(E)
+        kb, vb = prompt_cache_to_blocks(
+            {"k": c1["k"][:, :, :nb * self.block_size],
+             "v": c1["v"][:, :, :nb * self.block_size]},
+            self.block_size)
+        self.caches["k"], self.caches["v"] = write_pool_blocks(
+            self.caches["k"], self.caches["v"],
+            jnp.asarray(blocks, jnp.int32), kb[:, ns:], vb[:, ns:])
 
     def begin(self, b: int, prompt, need_tokens: int) -> bool:
         """Reserve blocks for a chunked prefill; the slot's device row
@@ -1010,6 +1042,101 @@ class PagedKV(SequenceState):
                 "kv_capacity_blocks": cap}
 
 
+@functools.partial(jax.jit, donate_argnums=0)
+def commit_state_row(rows, one, b):
+    """Write one sequence's recurrent state ``one`` (leaves ``(L, 1,
+    ...)``) into slot ``b`` of the stacked rows (leaves ``(L, B, ...)``),
+    in place: one program whatever the slot."""
+    return jax.tree.map(
+        lambda big, x: jax.lax.dynamic_update_index_in_dim(
+            big, x[:, 0].astype(big.dtype), b, 1), rows, one)
+
+
+class PagedState(PagedKV):
+    """``PagedKV`` for a model's attention layers beside one row of
+    recurrent state per slot (``Model.recurrent_rows``: granitehybrid's
+    Mamba2 layers) in the same cache pytree (``caches["ssm"]``), so one
+    adapter owns both kinds of state.
+
+    A chunked prefill advances the slot's own row in place
+    (``Lane.advance_prefill_row``; its first chunk starts the row afresh)
+    beside a detached cache of the attention layers' K/V, which
+    ``finalize`` lands in the slot's blocks; a whole-prompt admission
+    lands its K/V blocks and its state row (``commit_state_row``).  The
+    tick program advances the rows of decoding slots in place (its lane
+    donates the cache) and leaves the others alone.  A retired slot's row
+    holds its last state until the next occupant's prefill starts it
+    afresh.  Every prompt's detached K/V has the slot's full length, so
+    one chunk program and one landing serve every prompt.
+
+    Not yet: prefix sharing (shared blocks carry no state at the prefix's
+    end, so every prompt prefills whole) and swap (rows are not staged to
+    the host, so no slot is a preemption victim: a full pool defers
+    admission instead)."""
+
+    def __init__(self, lane: "Lane", params, batch: int, slot_len: int,
+                 block_size: int, num_blocks: Optional[int] = None, *,
+                 data_shards: int = 1, kv_ways: int = 1):
+        if data_shards > 1 or kv_ways > 1:
+            raise ValueError("recurrent state rows are not sharded; serve "
+                             f"family {lane.model.cfg.family!r} off the mesh")
+        super().__init__(lane, params, batch, slot_len, block_size,
+                         num_blocks)
+        self._rows = batch
+        self._row_bytes = sum(x.nbytes for x in
+                              jax.tree.leaves(self.caches["ssm"])) // batch
+
+    @property
+    def state_rows(self) -> int:
+        return self._rows
+
+    @property
+    def state_row_bytes(self) -> int:
+        return self._row_bytes
+
+    @property
+    def capacity_bytes(self) -> int:
+        return super().capacity_bytes + self._rows * self._row_bytes
+
+    def detached_len(self, entry_count: int) -> int:
+        return self.max_blocks * self.block_size
+
+    def advance_prefill(self, b: int, params, job: dict) -> bool:
+        finished, self.caches["ssm"] = self.lane.advance_prefill_row(
+            params, job, self.caches["ssm"], b)
+        return finished
+
+    def _write_blocks(self, b: int, E: int, blocks: List[int], ns: int,
+                      c1) -> None:
+        """Every block of the prefilled cache, those past the prompt's own
+        into the trap (one program for every prompt of a detached
+        prefill, whose cache has the slot's full length), then, for a
+        whole-prompt prefill, the slot's state row (a chunked one is in
+        the row already)."""
+        kb, vb = prompt_cache_to_blocks(c1, self.block_size)
+        ids = np.full((kb.shape[1],), self.pool.trap(b), np.int32)
+        ids[:len(blocks)] = blocks
+        self.caches["k"], self.caches["v"] = write_pool_blocks(
+            self.caches["k"], self.caches["v"], jnp.asarray(ids), kb, vb)
+        if "ssm" in c1:
+            self.caches["ssm"] = commit_state_row(self.caches["ssm"],
+                                                  c1["ssm"], b)
+
+    def _lookup_prefix(self, entries: np.ndarray,
+                       shard: int = 0) -> Tuple[int, List[int]]:
+        return 0, []
+
+    def _register(self, entries: np.ndarray, blocks: List[int],
+                  shard: int = 0):
+        pass
+
+    def share_hints(self, prompts: List[Any]) -> List[bool]:
+        return [False] * len(prompts)
+
+    def swappable(self, b: int) -> bool:
+        return False
+
+
 # ---------------------------------------------------------------- lane
 class Lane:
     """Jitted batched machinery for ONE model in ONE layout: the batched
@@ -1046,6 +1173,34 @@ class Lane:
         # the full encoder output and is excluded for the same reason.
         self._bucket_prefill = layout in ("dense", "paged") and \
             model.cfg.family in ("dense", "moe", "vlm")
+        # a paged cache with a row of recurrent state per slot: a chunked
+        # prefill advances its slot's row between ticks, every chunk padded
+        # to one length with the count of real tokens (one chunk program
+        # for every prompt), and the tick leaves the rows of slots that do
+        # not decode alone
+        rowed = layout == "paged" and model.recurrent_rows
+        if rowed:
+            def extend_row(p, toks, kv, rows, b, n, first):
+                """A padded chunk advanced in slot ``b``'s own row of
+                ``rows`` (leaves ``(L, B, ...)``), started afresh on the
+                job's ``first`` chunk, beside the job's detached K/V."""
+                fresh = model.init_cache(1, 1)["ssm"]
+                row = jax.tree.map(
+                    lambda x, f: jnp.where(
+                        first, f.astype(x.dtype),
+                        jax.lax.dynamic_slice_in_dim(x, b, 1, 1)),
+                    rows, fresh)
+                _, c = model.extend_step(p, toks, {**kv, "ssm": row},
+                                         valid=n)
+                rows = jax.tree.map(
+                    lambda x, r: jax.lax.dynamic_update_slice_in_dim(
+                        x, r.astype(x.dtype), b, 1), rows, c.pop("ssm"))
+                return c, rows
+
+            self._jit_extend_row = jax.jit(extend_row, donate_argnums=(2, 3))
+            self._jit_kv_zeros = jax.jit(
+                lambda n: {k: v for k, v in model.init_cache(1, n).items()
+                           if k != "ssm"}, static_argnums=0)
         self._jit_prefill = jax.jit(
             lambda p, toks, max_seq: model.prefill(
                 p, {"tokens": toks}, max_seq=max_seq),
@@ -1068,7 +1223,12 @@ class Lane:
             exact tuple it always has, byte-identical."""
             def body(carry, r):
                 caches, tok, steps_left, unc_sum = carry
-                lg, caches = step(params, tok, caches)       # (B, V)
+                if rowed:
+                    # only decoding slots advance their state rows
+                    lg, caches = model.paged_decode_step(
+                        params, tok[:, :, 0], caches, live=steps_left > 0)
+                else:
+                    lg, caches = step(params, tok, caches)   # (B, V)
                 active = steps_left > 0
                 if temperature == 0.0:
                     nxt = jnp.argmax(lg, -1).astype(jnp.int32)
@@ -1096,7 +1256,11 @@ class Lane:
                 jax.lax.scan(body, carry, keys)
             return caches, tok, steps_left, unc_sum, toks, actives
 
-        self._chunk = jax.jit(chunk, static_argnames=("n_steps", "topk"))
+        # a paged cache with a row of recurrent state per slot is advanced
+        # in place: a second copy of every slot's state would not fit
+        donate = {"donate_argnames": ("caches",)} if rowed else {}
+        self._chunk = jax.jit(chunk, static_argnames=("n_steps", "topk"),
+                              **donate)
 
     def dense_side(self) -> "Lane":
         """This lane's model re-hosted on dense per-slot caches (cached
@@ -1175,6 +1339,28 @@ class Lane:
         job["done"] = done + take
         return job["done"] >= entries.size
 
+    def advance_prefill_row(self, params, job: dict, rows, b: int):
+        """``advance_prefill`` on a paged lane whose model keeps recurrent
+        state rows (``Model.recurrent_rows``): the chunk, padded to the
+        chunk length, advances slot ``b``'s own row of ``rows`` in place
+        (the job's first chunk starts it afresh), and the job's detached
+        cache holds the attention layers' K/V alone.  No detached copy of
+        the state exists, so a batch of prefills costs only their K/V.
+        One program for every chunk of every prompt.  Returns (finished,
+        rows)."""
+        entries, done, C = job["entries"], job["done"], job["chunk"]
+        take = min(C, entries.size - done)
+        padded = np.zeros((1, min(C, job["max_seq"] - done)), np.int32)
+        padded[0, :take] = entries[done:done + take]
+        kv = job["cache"]
+        if kv is None:
+            kv = self._jit_kv_zeros(job["max_seq"])
+        job["cache"], rows = self._jit_extend_row(
+            params, jnp.asarray(padded), kv, rows, jnp.int32(b),
+            jnp.int32(take), jnp.bool_(done == 0))
+        job["done"] = done + take
+        return job["done"] >= entries.size, rows
+
     def make_state(self, params, batch: int, slot_len: int, *,
                    need_tokens: Optional[Sequence[int]] = None,
                    num_blocks: Optional[int] = None) -> SequenceState:
@@ -1186,6 +1372,7 @@ class Lane:
                                batch)
         if self.layout == "dense":
             return self._place(DenseKV(self, params, batch, slot_len), batch)
+        paged = PagedState if self.model.recurrent_rows else PagedKV
         shards = self.data_shards if batch % max(self.data_shards, 1) == 0 \
             else 1
         if num_blocks is None and need_tokens is not None:
@@ -1206,8 +1393,8 @@ class Lane:
                 # peak-bytes stat tracks LIVE blocks, not this capacity)
                 num_blocks = 1 + pow2_steps(needed, 1 << 30)
         return self._place(
-            PagedKV(self, params, batch, slot_len, self.block_size,
-                    num_blocks, data_shards=shards, kv_ways=self.kv_ways),
+            paged(self, params, batch, slot_len, self.block_size,
+                  num_blocks, data_shards=shards, kv_ways=self.kv_ways),
             batch)
 
     def _place(self, state: SequenceState, batch: int) -> SequenceState:

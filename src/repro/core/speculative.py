@@ -377,6 +377,9 @@ class BatchedSpecDecoder:
                 in_axes=(None, 0, 0))
             self._round = jax.jit(self._self_round_impl)
             self._per_round = (gamma, gamma + 1)
+        # every round rewinds the states it drafted and verified on
+        self.rewindable = self._tops.rewindable and (
+            mode == "self" or self._dops.rewindable)
 
     @staticmethod
     def tree_supported(draft_model, target_model) -> bool:

@@ -18,7 +18,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.models import encdec, hybrid, ssm, transformer, xlstm
+from repro.models import (encdec, granite_hybrid, hybrid, ssm, transformer,
+                          xlstm)
 
 
 def cross_entropy(logits, labels, ignore: int = -1):
@@ -46,6 +47,8 @@ class Model:
             return xlstm.init_params(rng, cfg)
         if cfg.family == "hybrid":
             return hybrid.init_params(rng, cfg)
+        if cfg.family == "granitehybrid":
+            return granite_hybrid.init_params(rng, cfg)
         if cfg.family == "encdec":
             return encdec.init_params(rng, cfg)
         raise ValueError(cfg.family)
@@ -71,6 +74,9 @@ class Model:
         if cfg.family == "hybrid":
             return hybrid.forward(params, tokens, cfg, window=window, remat=remat,
                                   collect_hidden=collect_hidden)
+        if cfg.family == "granitehybrid":
+            return granite_hybrid.forward(params, tokens, cfg, remat=remat,
+                                          collect_hidden=collect_hidden)
         if cfg.family == "encdec":
             return encdec.forward(params, tokens, cfg, frames=batch["frames"],
                                   remat=remat, collect_hidden=collect_hidden)
@@ -97,6 +103,8 @@ class Model:
             return xlstm.init_cache(cfg, batch_size)
         if cfg.family == "hybrid":
             return hybrid.init_cache(cfg, batch_size, max_seq)
+        if cfg.family == "granitehybrid":
+            return granite_hybrid.init_cache(cfg, batch_size, max_seq)
         if cfg.family == "encdec":
             return encdec.init_cache(cfg, batch_size, max_seq)
         raise ValueError(cfg.family)
@@ -118,6 +126,8 @@ class Model:
         if cfg.family == "hybrid":
             return hybrid.prefill(params, tokens, cfg, max_seq=max_seq,
                                   window=window)
+        if cfg.family == "granitehybrid":
+            return granite_hybrid.prefill(params, tokens, cfg, max_seq=max_seq)
         if cfg.family == "encdec":
             return encdec.prefill(params, tokens, cfg, frames=batch["frames"],
                                   max_seq=max_seq)
@@ -133,17 +143,29 @@ class Model:
             return xlstm.decode_step(params, token, cache, cfg)
         if cfg.family == "hybrid":
             return hybrid.decode_step(params, token, cache, cfg, window=window)
+        if cfg.family == "granitehybrid":
+            return granite_hybrid.decode_step(params, token, cache, cfg)
         if cfg.family == "encdec":
             return encdec.decode_step(params, token, cache, cfg)
         raise ValueError(cfg.family)
 
     def extend_step(self, params, tokens, cache, *, window: int = 0,
-                    block_mask=None, q_positions=None):
+                    block_mask=None, q_positions=None, valid=None):
         """Multi-token cached decode (chunked prefill, speculative verify).
         tokens (B,T) -> (logits (B,T,V), cache).  ``block_mask`` is only
         supported for attention-based decoders (token trees); SSM/hybrid
-        recurrences are inherently linear-order (see DESIGN.md)."""
+        recurrences are inherently linear-order.  ``valid`` (traced int,
+        ``recurrent_rows`` families): only the first ``valid`` tokens are
+        real, the rest padding that leaves the cache as they left it."""
         cfg = self.cfg
+        if cfg.family == "granitehybrid":
+            if block_mask is not None or q_positions is not None:
+                raise ValueError("block_mask unsupported for family "
+                                 "'granitehybrid'")
+            return granite_hybrid.extend_step(params, tokens, cache, cfg,
+                                              valid=valid)
+        if valid is not None:
+            raise ValueError(f"valid unsupported for family {cfg.family!r}")
         if cfg.family in ("dense", "moe", "vlm"):
             return transformer.extend_step(params, tokens, cache, cfg,
                                            window=window, block_mask=block_mask,
@@ -163,12 +185,22 @@ class Model:
     # ------------------------------------------------------------ paged kv
     @property
     def paged_kv(self) -> bool:
-        """True if the family's cache is a pure self-attention KV cache that
-        the serving scheduler can lay out as a shared block pool + block
-        tables (see ``core/paged_cache.py``).  Recurrent state (ssm/hybrid)
-        has no sequence axis to page; encdec carries cross-attention K/V
-        pinned to the encoder length."""
-        return self.cfg.family in ("dense", "moe", "vlm")
+        """True if the family's attention K/V can be laid out as a shared
+        block pool + block tables (see ``core/paged_cache.py``): the KV
+        transformer families, and granitehybrid, whose attention layers
+        page beside a per-slot row of Mamba2 state (``recurrent_rows``).
+        Other recurrent state (ssm/hybrid) has no sequence axis to page;
+        encdec carries cross-attention K/V pinned to the encoder length."""
+        return self.cfg.family in ("dense", "moe", "vlm", "granitehybrid")
+
+    @property
+    def recurrent_rows(self) -> bool:
+        """True if the paged cache also holds a fixed row of recurrent
+        state per slot (``cache["ssm"]``), which the pool's block tables
+        do not cover.  Such a family's ``extend_step`` takes ``valid``, so
+        that prefill chunks padded to one length leave the state as their
+        real tokens left it."""
+        return self.cfg.family == "granitehybrid"
 
     def _require_paged(self):
         if not self.paged_kv:
@@ -179,17 +211,30 @@ class Model:
     def init_paged_cache(self, num_blocks: int, block_size: int, batch: int,
                          max_blocks: int):
         self._require_paged()
+        if self.cfg.family == "granitehybrid":
+            return granite_hybrid.init_paged_cache(self.cfg, num_blocks,
+                                                   block_size, batch,
+                                                   max_blocks)
         return transformer.init_paged_cache(self.cfg, num_blocks, block_size,
                                             batch, max_blocks)
 
     def paged_decode_step(self, params, token, cache, *,
-                          attn_backend: str = "auto"):
+                          attn_backend: str = "auto", live=None):
         """One decode step over a paged cache. token (B,1) -> (logits (B,V),
         cache).  ``attn_backend``: "auto" (TPU: Pallas paged kernel —
         windowed variant under ``cfg.sliding_window``; CPU: jnp oracle),
         "kernel", "ref", or "gather" (the full-width block-table gather,
-        kept only as a test oracle — it is off every decode hot path)."""
+        kept only as a test oracle — it is off every decode hot path).
+        ``live`` ((B,) bool, ``recurrent_rows`` families): the slots whose
+        state rows advance; the others keep theirs."""
         self._require_paged()
+        if self.cfg.family == "granitehybrid":
+            return granite_hybrid.paged_decode_step(
+                params, token, cache, self.cfg, attn_backend=attn_backend,
+                live=live)
+        if live is not None:
+            raise ValueError(f"live unsupported for family "
+                             f"{self.cfg.family!r}")
         return transformer.paged_decode_step(params, token, cache, self.cfg,
                                              attn_backend=attn_backend)
 
@@ -197,6 +242,9 @@ class Model:
         """Multi-token cached decode over a paged cache. tokens (B,T) ->
         (logits (B,T,V), cache)."""
         self._require_paged()
+        if self.cfg.family == "granitehybrid":
+            return granite_hybrid.paged_extend_step(params, tokens, cache,
+                                                    self.cfg)
         return transformer.paged_extend_step(params, tokens, cache, self.cfg)
 
     @property
@@ -224,6 +272,9 @@ class Model:
             return xlstm.replay_step(params, tokens, cache, count, cfg)
         if cfg.family == "hybrid":
             return hybrid.replay_step(params, tokens, cache, count, cfg)
+        if cfg.family == "granitehybrid":
+            return granite_hybrid.replay_step(params, tokens, cache, count,
+                                              cfg)
         raise ValueError(f"replay_step is for recurrent-state families; "
                          f"{cfg.family!r} caches rewind via pos")
 

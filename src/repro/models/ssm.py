@@ -139,10 +139,12 @@ def init_conv(rng, channels: int, width: int, dtype):
     }
 
 
-def causal_conv(p, x, state=None):
+def causal_conv(p, x, state=None, valid=None):
     """Depthwise causal conv. x: (B,S,C) -> (B,S,C); returns (y, new_state).
     ``state``: (B, W-1, C) trailing inputs from the previous segment (zeros
-    at sequence start)."""
+    at sequence start).  ``valid`` (traced int): only ``x[:, :valid]`` are
+    real inputs, the rest padding; the new state ends at the last real
+    one."""
     w = p["w"]                       # (W, C)
     W = w.shape[0]
     if state is None:
@@ -151,7 +153,9 @@ def causal_conv(p, x, state=None):
         xp = jnp.concatenate([state.astype(x.dtype), x], axis=1)
     y = sum(xp[:, i:i + x.shape[1], :] * w[i] for i in range(W))
     y = y + p["b"]
-    if W > 1:
+    if W > 1 and valid is not None:
+        state = jax.lax.dynamic_slice_in_dim(xp, valid, W - 1, axis=1)
+    elif W > 1:
         state = xp[:, -(W - 1):, :]   # last W-1 raw inputs
     else:
         state = jnp.zeros((x.shape[0], 0, x.shape[2]), x.dtype)
@@ -192,26 +196,38 @@ def _mamba_dims(cfg):
 
 def _mamba_split(p, x, cfg):
     di, N, P, H = _mamba_dims(cfg)
+    if "in_proj" not in p:
+        # the projection held as its three column blocks (granitehybrid:
+        # widths the chip tiles without a relayout of the whole stack)
+        return x @ p["in_z"], x @ p["in_xbc"], x @ p["in_dt"]
     zxbcdt = x @ p["in_proj"]
     z, xbc, dt = jnp.split(zxbcdt, [di, 2 * di + 2 * N], axis=-1)
     return z, xbc, dt
 
 
-def mamba2_forward(p, x, cfg, cache=None):
+def mamba2_forward(p, x, cfg, cache=None, valid=None):
     """x: (B,S,d) -> (y (B,S,d), final GLA state + conv state).
     ``cache``: optional {"gla": GLAState, "conv": (B,W-1,C)} to continue
-    from a previous segment (chunked prefill / speculative extension)."""
+    from a previous segment (chunked prefill / speculative extension).
+    ``valid`` (traced int): only the first ``valid`` tokens are real; the
+    padding after them leaves both states as the real tokens left them
+    (no decay, no input), so one padded shape serves every length."""
     from repro.models.layers import rmsnorm
     B, S, d = x.shape
     di, N, P, H = _mamba_dims(cfg)
     z, xbc, dt = _mamba_split(p, x, cfg)
     xbc, conv_state = causal_conv(p["conv"], xbc,
-                                  state=None if cache is None else cache["conv"])
+                                  state=None if cache is None else cache["conv"],
+                                  valid=valid)
     xbc = jax.nn.silu(xbc)
     xs, Bm, Cm = jnp.split(xbc, [di, di + N], axis=-1)
     delta = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])   # (B,S,H)
     log_a = -jnp.exp(p["A_log"]) * delta
     log_i = jnp.log(delta + 1e-9)
+    if valid is not None:
+        real = (jnp.arange(S) < valid)[None, :, None]
+        log_a = jnp.where(real, log_a, 0.0)
+        log_i = jnp.where(real, log_i, _NEG)
     v = xs.reshape(B, S, H, P)
     k = jnp.broadcast_to(Bm[:, :, None, :], (B, S, H, N))
     q = jnp.broadcast_to(Cm[:, :, None, :], (B, S, H, N))

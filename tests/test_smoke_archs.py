@@ -66,6 +66,7 @@ def test_full_config_fields(arch):
         "nemotron-4-15b": (32, 6144, 48, 8, 24576, 256000),
         "zamba2-2.7b": (54, 2560, 32, 32, 10240, 32000),
         "granite-8b": (36, 4096, 32, 8, 14336, 49152),
+        "granite-4.0-h-micro": (40, 2048, 32, 8, 8192, 100352),
     }[arch]
     got = (cfg.num_layers, cfg.d_model, cfg.num_heads, cfg.num_kv_heads,
            cfg.d_ff, cfg.vocab_size)
@@ -78,3 +79,13 @@ def test_full_config_fields(arch):
         assert cfg.ssm_state == 64
     if arch == "mamba2-370m":
         assert (cfg.ssm_state, cfg.ssm_head_dim) == (128, 64)
+    if arch == "granite-4.0-h-micro":
+        assert [i for i, k in enumerate(cfg.layer_types)
+                if k == "attention"] == [5, 15, 25, 35]
+        assert (cfg.ssm_state, cfg.ssm_head_dim, cfg.ssm_expand,
+                cfg.conv_kernel, cfg.head_dim) == (128, 64, 2, 4, 64)
+        assert (cfg.embedding_multiplier, cfg.attention_multiplier,
+                cfg.residual_multiplier, cfg.logits_scaling) == \
+            (12.0, 1 / 64, 0.22, 8.0)
+        assert not cfg.use_rope
+        assert abs(cfg.n_params() - 3.19e9) < 0.01e9

@@ -153,3 +153,57 @@ def test_paged_decode_step_compiles_on_model_mesh(topo, mosaic, name, layers,
             p, t, c, attn_backend="kernel")).lower(
                 params, tok, cache).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_granite_hybrid_paged_decode_tick_compiles(one_chip, mosaic):
+    """One decode tick of granite-4.0-h-micro at its published widths over
+    its first seven layers (Mamba2 runs either side of an attention
+    layer), as the engine's lane builds it: the paged kernel in the
+    attention layer, every slot's state row advanced in place (the cache
+    is donated, so the state is aliased and not copied)."""
+    import numpy as np
+    from repro.core.seq_state import Lane
+    cfg = get_config("granite-4.0-h-micro")
+    cfg = cfg.replace(num_layers=7, layer_types=cfg.layer_types[:7])
+    model = Model(cfg)
+    params, cache = _paged_step_args(model, lambda path, x: one_chip)
+    lane = Lane(model, "entropy", 0.0, layout="paged", block_size=BLOCK)
+    compiled = lane._chunk.lower(
+        params, cache, _shape((B, 1, 1), jnp.int32, one_chip),
+        _shape((B,), jnp.int32, one_chip), _shape((B,), jnp.float32, one_chip),
+        _shape((2,), jnp.uint32, one_chip), _shape((), jnp.int32, one_chip),
+        n_steps=2).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    state = sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                for x in jax.tree.leaves(cache["ssm"]))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= state
+    assert mem.temp_size_in_bytes < state
+
+
+def test_granite_hybrid_prefill_chunk_in_row_compiles(one_chip):
+    """A chunked-prefill chunk of granite-4.0-h-micro at its published
+    widths over its first seven layers, in a slot's own row of the
+    stacked state: the rows are donated and aliased, so a chunk writes
+    one row in place and copies no other."""
+    import numpy as np
+    from repro.core.seq_state import Lane
+    cfg = get_config("granite-4.0-h-micro")
+    cfg = cfg.replace(num_layers=7, layer_types=cfg.layer_types[:7])
+    model = Model(cfg)
+    params, cache = _paged_step_args(model, lambda path, x: one_chip)
+    rows = cache["ssm"]
+    kv = jax.tree.map(lambda x: _shape(x.shape, x.dtype, one_chip),
+                      jax.eval_shape(lambda: {
+                          k: v for k, v in model.init_cache(
+                              1, MAX_BLOCKS * BLOCK).items() if k != "ssm"}))
+    lane = Lane(model, "entropy", 0.0, layout="paged", block_size=BLOCK)
+    scalar = _shape((), jnp.int32, one_chip)
+    compiled = lane._jit_extend_row.lower(
+        params, _shape((1, 16), jnp.int32, one_chip), kv, rows, scalar,
+        scalar, _shape((), jnp.bool_, one_chip)).compile()
+    state = sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                for x in jax.tree.leaves(rows))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= state
+    assert mem.temp_size_in_bytes < state / B
